@@ -198,7 +198,10 @@ def main(argv=None):
     _check_suite_names(args.only, "--only")
 
     from benchmarks.common import ensure_out
+    from repro.compile_cache import enable_compile_cache
     from repro.obs.sink import JsonlSink
+
+    enable_compile_cache()
 
     sink = JsonlSink(os.path.join(ensure_out(), "events.jsonl"))
     prov = provenance()

@@ -22,7 +22,7 @@ the jaxpr level:
     ``split`` inside a ``scan``/``while`` body (or ``fold_in`` with
     loop-invariant data): every iteration draws the SAME randoms.
 
-Consumption counts through call boundaries: passing a key into a ``pjit``/
+Consumption counts through call boundaries: passing a key into a ``jit``/
 ``scan``/``cond`` whose body consumes it is ONE consumption at the caller
 (reuse *inside* the callee is reported when its own scope is linted).
 Data operands of ``fold_in`` are translated across the boundary so the
@@ -100,7 +100,7 @@ def _call_invar_maps(eqn):
     elif name == "cond":
         for br in params["branches"]:
             yield unwrap(br), {i: i - 1 for i in range(1, n)}
-    elif name == "pjit" and "jaxpr" in params:
+    elif name == "jit" and "jaxpr" in params:
         yield unwrap(params["jaxpr"]), {i: i for i in range(n)}
     elif "call_jaxpr" in params:
         yield unwrap(params["call_jaxpr"]), {i: i for i in range(n)}

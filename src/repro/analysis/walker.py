@@ -4,7 +4,7 @@ The contract auditor never executes a cell — every pass works on the
 traced jaxpr.  This module owns the mechanics all of them share:
 
   * ``iter_eqns`` — depth-first equation iteration that recurses through
-    every higher-order primitive (``scan``/``while``/``cond``/``pjit``/
+    every higher-order primitive (``scan``/``while``/``cond``/``jit``/
     ``custom_*``/``pallas_call``) by inspecting eqn params for nested
     jaxprs, so a pass never needs to know the param-name zoo;
   * ``count_pallas_calls`` — the launch counter (the generalisation of the
@@ -17,7 +17,7 @@ traced jaxpr.  This module owns the mechanics all of them share:
     indices (e.g. ``key_to_seed``'s scalar picks) are NOT flagged — taint
     starts only at kernel outputs.
 
-Higher-order invar mapping is positional and primitive-specific (pjit is
+Higher-order invar mapping is positional and primitive-specific (jit is
 1:1; scan is consts+carry+xs; while is cond_consts+body_consts+carry;
 cond is index+operands); loop carries are iterated to a fixpoint, which
 terminates because taint only grows and is bounded by the carry width.
@@ -66,7 +66,7 @@ def subjaxprs(eqn) -> Iterator[tuple[str, jex_core.Jaxpr]]:
 def iter_eqns(jaxpr: JaxprLike, *, into_kernels: bool = True, _path: str = ""):
     """Depth-first ``(eqn, path)`` iteration through nested jaxprs.
 
-    ``path`` is a human-readable breadcrumb ("scan/pjit") for diagnostics.
+    ``path`` is a human-readable breadcrumb ("scan/jit") for diagnostics.
     ``into_kernels=False`` stops at ``pallas_call`` boundaries — kernel
     bodies address VMEM, so HBM-level passes must not look inside them.
     """
@@ -236,7 +236,7 @@ class _TaintScope:
             for br in branches:
                 out |= self.run(unwrap(br), op_taint, path)
             return out
-        if name == "pjit" or (
+        if name == "jit" or (
             name in ("closed_call", "core_call", "remat2", "checkpoint")
             and "jaxpr" in params
         ):

@@ -1,10 +1,9 @@
-"""Compatibility shims for the pinned JAX version.
+"""Thin helpers over the pinned JAX 0.9 API.
 
-``jax.tree_util.keystr(path, simple=True, separator="/")`` only exists in
-newer JAX releases; the pinned 0.4.x ``keystr`` takes the key path alone
-and renders the verbose ``['a'].b[0]`` form.  Checkpoint manifests and the
-partitioning tables key leaves by the SIMPLE slash-joined form (``a/b/0``),
-so the formatter lives here, version-independent.
+``make_mesh`` forces Auto-typed mesh axes (``jax.make_mesh`` defaults to
+Explicit axes on 0.9, which the sharding rules here do not use), and
+``keystr_simple`` renders key paths in the slash-joined form that
+checkpoint manifests and the partitioning tables key leaves by.
 """
 
 from __future__ import annotations
@@ -13,54 +12,18 @@ import jax
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """``jax.make_mesh`` with explicitly-Auto axis types on any JAX version.
-
-    ``jax.sharding.AxisType`` only exists on newer JAX; on the pinned
-    0.4.x every mesh axis is Auto-typed implicitly, so the kwarg is simply
-    dropped there.
-    """
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
     kwargs = {} if devices is None else {"devices": devices}
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        kwargs["axis_types"] = (axis_type.Auto,) * len(axis_names)
-    return jax.make_mesh(axis_shapes, axis_names, **kwargs)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_rep=False):
-    """``jax.shard_map`` on new JAX, ``jax.experimental.shard_map`` on 0.4.x.
-
-    The experimental form spells the replication-check kwarg ``check_rep``;
-    the graduated form renamed it ``check_vma`` — normalised here.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_rep
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_rep
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names), **kwargs,
     )
 
 
-def axis_size(axis_name):
-    """``lax.axis_size`` on new JAX; the classic ``psum(1, axis)`` constant
-    fold (which returns a static int for a literal operand) on 0.4.x."""
-    from jax import lax
-
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
-
-
 def keystr_simple(path, separator: str = "/") -> str:
-    """Render a JAX key path as simple names joined by ``separator``.
-
-    Equivalent to ``jax.tree_util.keystr(path, simple=True,
-    separator=separator)`` on new JAX, but works on any version: each
-    entry contributes its bare payload (dict key, sequence index, or
-    attribute name) with no quotes or brackets.
-    """
+    """Render a JAX key path as simple names joined by ``separator``
+    (``a/b/0``): each entry contributes its bare payload (dict key,
+    sequence index, or attribute name) with no quotes or brackets."""
     parts = []
     for entry in path:
         for attr in ("key", "idx", "name"):

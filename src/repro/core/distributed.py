@@ -38,7 +38,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import axis_size, shard_map
 from repro.core.resamplers.megopolis import megopolis_indices
 from repro.kernels.common import hash_uniform, key_to_seed, murmur3_fmix
 
@@ -94,7 +93,7 @@ def megopolis_shard(
     ``offsets_shard``: list[int] (static mode) or int32[B] traced (dynamic).
     """
     n_local = local_weights.shape[0]
-    n_shards = axis_size(axis_name)
+    n_shards = lax.axis_size(axis_name)
     s = lax.axis_index(axis_name)
     i_local = jnp.arange(n_local, dtype=jnp.int32)
     i_global = s * n_local + i_local
@@ -140,7 +139,7 @@ def gather_ancestors(x_local: jnp.ndarray, ancestors_global: jnp.ndarray, *, axi
 def island_exchange(x_local: jnp.ndarray, *, axis_name: str, fraction: float = 0.25):
     """Ring-mix a leading fraction of local particles with the next shard
     (island-model particle exchange; Vergé et al. [46])."""
-    n_shards = axis_size(axis_name)
+    n_shards = lax.axis_size(axis_name)
     m = max(1, int(x_local.shape[0] * fraction))
     perm = [(src, (src + 1) % n_shards) for src in range(int(n_shards))]
     head = lax.ppermute(x_local[:m], axis_name, perm)
@@ -216,11 +215,12 @@ def make_distributed_resampler(
             schedule=schedule,
         )
 
-    shard_fn = shard_map(
+    shard_fn = jax.shard_map(
         impl,
         mesh=mesh,
         in_specs=(P(), P(), P(), P(axis_name)),
         out_specs=P(axis_name),
+        check_vma=False,
     )
 
     @jax.jit

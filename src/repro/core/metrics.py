@@ -9,6 +9,8 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro.reduction import tree_sum
+
 
 def _guarded_shift(log_w: jnp.ndarray, axis: int) -> jnp.ndarray:
     """Shift-by-max stabiliser, guarded against an all-``-inf`` row: a
@@ -92,12 +94,13 @@ def effective_sample_size(log_w: jnp.ndarray, axis: int = -1) -> jnp.ndarray:
     in ``repro.core.distributed.effective_sample_size``.
 
     The fused step kernels (``kernels/common.step_stats``) re-derive this
-    decomposition term for term over the same flat [N] reduction shape, so
-    the on-chip ESS is bit-identical to this host value.
+    decomposition term for term with the same ``tree_sum`` order, so the
+    on-chip ESS is bit-identical to this host value wherever the two
+    backends' ``exp`` agree.
     """
     w = normalise_log_weights(log_w, axis=axis)
-    s1 = jnp.sum(w, axis=axis)
-    s2 = jnp.sum(w * w, axis=axis)
+    s1 = tree_sum(w, axis=axis)
+    s2 = tree_sum(w * w, axis=axis)
     # Dtype-aware guard: 1e-30 is a flush-to-zero subnormal in f16, which
     # would leave the degenerate-row division at inf (bitwise unchanged for
     # f32/bf16 inputs).
@@ -113,7 +116,7 @@ def log_mean_weight(log_w: jnp.ndarray, axis: int = -1) -> jnp.ndarray:
     and kernel share one exact f32 formula — a fused ``step`` adds a
     bit-identical increment)."""
     m = _guarded_shift(log_w, axis)
-    s1 = jnp.sum(jnp.exp(log_w - m), axis=axis)
+    s1 = tree_sum(jnp.exp(log_w - m), axis=axis)
     n = log_w.shape[axis]
     return (jnp.squeeze(m, axis=axis) + jnp.log(s1)) - jnp.log(jnp.float32(n))
 
@@ -125,11 +128,11 @@ def max_normalised_weight(log_w: jnp.ndarray, axis: int = -1) -> jnp.ndarray:
 
     Shares the shift-by-max decomposition of ``effective_sample_size`` term
     for term; the fused step kernels (``kernels/common.step_stats``) compute
-    the same ``max(w) / max(Σw, floor)`` over the same flat [N] reduction, so
-    the on-chip value is bit-identical to this host value (DESIGN.md §15).
+    the same ``max(w) / max(Σw, floor)`` with the same ``tree_sum`` order
+    (DESIGN.md §15).
     """
     w = normalise_log_weights(log_w, axis=axis)
-    s1 = jnp.sum(w, axis=axis)
+    s1 = tree_sum(w, axis=axis)
     return jnp.max(w, axis=axis) / jnp.maximum(s1, _tiny_floor(s1.dtype))
 
 

@@ -66,14 +66,36 @@ def megopolis(
         offsets = jax.random.randint(key_off, (num_iters,), 0, n)
     i = jnp.arange(n, dtype=jnp.int32)
 
-    def body(b, k):
+    def body(b, carry):
+        k, wk = carry
         j = megopolis_indices(i, offsets[b], segment, n).astype(jnp.int32)
+        w_j = _comparison_weights(weights, offsets[b], segment, j)
         u = jax.random.uniform(jax.random.fold_in(key_u, b), (n,), weights.dtype)
         # u <= w[j] / w[k]  <=>  u * w[k] <= w[j]   (division-free, w >= 0)
-        accept = u * weights[k] <= weights[j]
-        return jnp.where(accept, j, k)
+        accept = u * wk <= w_j
+        return jnp.where(accept, j, k), jnp.where(accept, w_j, wk)
 
-    return jax.lax.fori_loop(0, num_iters, body, i)
+    # w[k] is carried by value beside k (exactly ``weights[k]``), as the
+    # kernel carries it, so no iteration gathers by the ancestor.
+    k, _ = jax.lax.fori_loop(0, num_iters, body, (i, weights))
+    return k
+
+
+def _comparison_weights(weights: jnp.ndarray, offset, segment: int, j) -> jnp.ndarray:
+    """``weights[j]`` for the comparison map of one offset.
+
+    Where N is whole segments the map is a rotation of the ``(N/S, S)``
+    view — segments by ``o // S``, lanes within a segment by ``o mod S`` —
+    so the values come from two rolls instead of an N-element gather (the
+    paper's coalescing, in XLA form; an XLA gather of 2^20 words costs
+    milliseconds on a TPU).  Otherwise the index map wraps mid-segment and
+    ``weights[j]`` is gathered."""
+    n = weights.shape[0]
+    if n % segment:
+        return weights[j]
+    w2 = weights.reshape(n // segment, segment)
+    w2 = jnp.roll(w2, -(offset // segment), axis=0)
+    return jnp.roll(w2, -(offset % segment), axis=1).reshape(n)
 
 
 def megopolis_batch(

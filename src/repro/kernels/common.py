@@ -11,10 +11,13 @@ TPU-friendly (integer mul/xor/shift on the VPU).
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.reduction import block_tree_sum
 from repro.resilience.errors import VmemBudgetExceeded
 
 # NOTE: all scalar constants below are *numpy* scalars so they inline as
@@ -36,9 +39,12 @@ def tile_lane_ids(t) -> jnp.ndarray:
     return t * TILE + row * LANES + col
 
 # Residency budget for kernels that keep a whole f32[N] array VMEM-resident
-# (the Metropolis/rejection random gather, the search kernel's CDF): ~4 MB,
-# comfortably inside a 16 MB VMEM core.  ONE definition — DESIGN.md §2
-# cites it, three ops modules enforce it.  The budget is BYTES underneath
+# (the Metropolis/rejection random gather, the search kernel's CDF, the fused
+# step's prelude): 4 MiB.  On a TPU v5e (128 MiB of VMEM per core) a kernel
+# may use 16 MiB unless it raises ``vmem_limit_bytes``, and a block whose
+# index map is constant over the grid is held once (both as the installed
+# compiler accepts them, DESIGN.md §2).  ONE definition — DESIGN.md §2
+# cites it, the ops modules enforce it.  The budget is BYTES underneath
 # (MAX_VMEM_PARTICLE_BYTES): compressed planes (DESIGN.md §14) double the
 # admissible N because a bf16/f16 word is half an f32 word.
 MAX_VMEM_PARTICLES = 1 << 20
@@ -123,9 +129,11 @@ def compress_plane(x: jnp.ndarray, plane_dtype) -> jnp.ndarray:
 # (R, 128) layout and needs no padding.
 STATE_PLANE_TILE = SUBLANES
 
-# Resident-state budget in f32 words (n * d_pad): ~8 MB, alongside at most
-# ~4 MB of resident weights (MAX_VMEM_PARTICLES) still inside a 16 MB core.
-# Bytes underneath (MAX_VMEM_STATE_BYTES): compressed planes double the edge.
+# Resident-state budget in f32 words (n * d_pad): 8 MiB, for the families
+# that gather from a resident plane stack (Megopolis carries state by value
+# and has no such cap).  With 4 MiB of resident weights that is 12 MiB, inside
+# the 16 MiB a v5e kernel may use.  Bytes underneath (MAX_VMEM_STATE_BYTES):
+# compressed planes double the edge.
 MAX_VMEM_STATE = 2 * MAX_VMEM_PARTICLES
 MAX_VMEM_STATE_BYTES = 4 * MAX_VMEM_STATE
 
@@ -145,9 +153,12 @@ def vmem_budget_bytes() -> int:
     Every plane the word budgets admit may be resident at most TWICE —
     pallas kernels take inputs and outputs as separate refs, so a fused
     step at the residency edge holds state in + state out plus a few
-    weight planes (measured worst case: the prefix-family fused step at
-    N*pad_state_dim == MAX_VMEM_STATE costs 19.0 MiB).  At the defaults
-    this is 2 * (4 MB + 8 MB) + 2 MB = 26 MB inside a 32 MB core."""
+    weight planes (the analyzer's estimate for the prefix-family fused step
+    at N*pad_state_dim == MAX_VMEM_STATE is 19.0 MiB).  At the defaults this
+    is 2 * (4 MiB + 8 MiB) + 2 MiB = 26 MiB: more than the 16 MiB a v5e
+    kernel may use without raising ``vmem_limit_bytes``, which no kernel
+    here does.  It prices the resident-stack families, none of which Mosaic
+    compiles yet (DESIGN.md §2); the Megopolis kernels stay far below it."""
     return 2 * 4 * (MAX_VMEM_PARTICLES + MAX_VMEM_STATE) + VMEM_FOOTPRINT_SLACK_BYTES
 
 
@@ -210,16 +221,16 @@ def state_itemsize(particles: jnp.ndarray, plane_dtype) -> int:
 
 
 def run_fused_bank(launch, weights: jnp.ndarray, particles: jnp.ndarray, who: str,
-                   plane_dtype="float32"):
+                   plane_dtype="float32", state_resident=True):
     """Shared bank scaffolding for every family's fused apply launch:
-    residency check, per-row plane pack (+ §14 wire narrowing),
+    residency check (for kernels that keep the whole state stack in VMEM,
+    ``state_resident``), per-row plane pack (+ §14 wire narrowing),
     ``launch(w3, planes4d) -> (k3, out4d)``, per-row unpack.  Returns
     ``(particles'[B, N, ...], ancestors int32[B, N])``."""
-    import jax
-
     bsz, n = weights.shape
-    check_state_resident(n, state_dim_of(particles, n, who, lead=2), who,
-                         itemsize=state_itemsize(particles, plane_dtype))
+    d = state_dim_of(particles, n, who, lead=2)
+    if state_resident:
+        check_state_resident(n, d, who, itemsize=state_itemsize(particles, plane_dtype))
     w3 = compress_plane(weights.reshape(bsz, n // LANES, LANES), plane_dtype)
     planes = compress_plane(
         jax.vmap(lambda p: pack_state_planes(p)[0])(particles), plane_dtype
@@ -279,18 +290,20 @@ def gather_state(planes: jnp.ndarray, k_global: jnp.ndarray) -> jnp.ndarray:
     )
 
 
-def step_stats(lw_flat: jnp.ndarray, n_total: int):
-    """Fused-step prelude statistics from a resident flat log-weight vector:
-    ``(m, ess_norm, log_evidence_incr, max_weight, degenerate)``.
+def step_stats(lw: jnp.ndarray, n_total: int):
+    """Fused-step prelude statistics from a resident ``(rows, 128)``
+    log-weight block: ``(m, ess_norm, log_evidence_incr, max_weight,
+    degenerate)``.
 
     Mirrors ``repro.core.metrics`` term for term — guarded shift-by-max
-    (``normalise_log_weights``), ``(Σw)²/max(Σw², 1e-30)`` over the SAME
-    flat [N] reduction shape (``effective_sample_size``), the
-    ``m + log(Σw) - log(N)`` decomposition (``log_mean_weight``), and
-    ``max(w)/max(Σw, 1e-30)`` (``max_normalised_weight``).  Kernel bodies
-    MUST reshape their (rows, 128) log-weight block to flat [N] before
-    calling: a 2-D reduction changes the f32 summation tree and breaks
-    bit-parity with the host helpers.
+    (``normalise_log_weights``), ``(Σw)²/max(Σw², 1e-30)``
+    (``effective_sample_size``), the ``m + log(Σw) - log(N)`` decomposition
+    (``log_mean_weight``), and ``max(w)/max(Σw, 1e-30)``
+    (``max_normalised_weight``) — with every sum in the host helpers'
+    ``block_tree_sum`` order, so the stats are bit-identical to the host's
+    wherever the two ``exp`` agree (always in interpret mode on CPU).  The
+    block stays 2-D as it lies in VMEM: a flat ``f32[N]`` value costs
+    Mosaic one (8, 128) tile per 128 words and overflows VMEM at N = 2^18.
 
     ``degenerate`` is the §16 collapsed-bank flag (``~isfinite(max)``:
     all-``-inf``, any nan/+inf — ``metrics.degenerate_log_weights``).  Where
@@ -301,14 +314,14 @@ def step_stats(lw_flat: jnp.ndarray, n_total: int):
     the truthful evidence of a dead bank, and the step's where-select zeroes
     it on the untriggered branch exactly as the host does).
     """
-    m_raw = jnp.max(lw_flat)
+    m_raw = jnp.max(lw)
     deg = ~jnp.isfinite(m_raw)
     m = jnp.where(deg, jnp.zeros_like(m_raw), m_raw)
-    w_raw = jnp.exp(lw_flat - m)
-    incr = (m + jnp.log(jnp.sum(w_raw))) - jnp.log(jnp.float32(n_total))
+    w_raw = jnp.exp(lw - m)
+    incr = (m + jnp.log(block_tree_sum(w_raw))) - jnp.log(jnp.float32(n_total))
     w = jnp.where(deg, jnp.full_like(w_raw, 1.0 / n_total), w_raw)
-    s1 = jnp.sum(w)
-    s2 = jnp.sum(w * w)
+    s1 = block_tree_sum(w)
+    s2 = block_tree_sum(w * w)
     ess = jnp.square(s1) / jnp.maximum(s2, 1e-30)
     ess_norm = ess / jnp.float32(n_total)
     maxw = jnp.max(w) / jnp.maximum(s1, 1e-30)
@@ -332,19 +345,19 @@ def gather_state_full(planes: jnp.ndarray, k_global: jnp.ndarray) -> jnp.ndarray
 
 
 def run_step_bank(launch, log_weights: jnp.ndarray, particles: jnp.ndarray, who: str,
-                  plane_dtype="float32"):
+                  plane_dtype="float32", state_resident=True):
     """Bank scaffolding for every family's fused STEP launch — the step
-    analogue of ``run_fused_bank``: residency check, per-row plane pack,
-    ``launch(lw3, planes4d) -> (k3, out4d, stats4)`` with ``stats4`` =
+    analogue of ``run_fused_bank``: residency check (``state_resident``),
+    per-row plane pack, ``launch(lw3, planes4d) -> (k3, out4d, stats4)``
+    with ``stats4`` =
     f32[B, 4] rows of (ess_norm, log_evidence_incr, resampled, max_weight)
     — the in-kernel StepStats vector of DESIGN.md §15 — then per-row
     unpack.  Returns ``(particles'[B, N, ...], ancestors int32[B, N],
     stats f32[B, 4])``."""
-    import jax
-
     bsz, n = log_weights.shape
-    check_state_resident(n, state_dim_of(particles, n, who, lead=2), who,
-                         itemsize=state_itemsize(particles, plane_dtype))
+    d = state_dim_of(particles, n, who, lead=2)
+    if state_resident:
+        check_state_resident(n, d, who, itemsize=state_itemsize(particles, plane_dtype))
     lw3 = compress_plane(log_weights.reshape(bsz, n // LANES, LANES), plane_dtype)
     planes = compress_plane(
         jax.vmap(lambda p: pack_state_planes(p)[0])(particles), plane_dtype
@@ -415,7 +428,9 @@ def _as_u32(x):
 def hash_uniform(seed, lane_index, iteration, dtype=jnp.float32) -> jnp.ndarray:
     """U[0,1) with 24 bits of mantissa entropy."""
     bits = hash_bits(seed, lane_index, iteration)
-    return (bits >> np.uint32(8)).astype(dtype) * (1.0 / (1 << 24))
+    # uint32 -> int32 -> float: exact (the value is below 2^24), and Mosaic
+    # has no unsigned-to-float conversion.
+    return (bits >> np.uint32(8)).astype(jnp.int32).astype(dtype) * (1.0 / (1 << 24))
 
 
 def hash_randint(seed, lane_index, iteration, bound) -> jnp.ndarray:
@@ -423,29 +438,34 @@ def hash_randint(seed, lane_index, iteration, bound) -> jnp.ndarray:
     return (hash_bits(seed, lane_index, iteration) % _as_u32(bound)).astype(jnp.int32)
 
 
+@jax.jit
 def flat_roll(x: jnp.ndarray, shift) -> jnp.ndarray:
     """Roll a (rows, 128) tile by ``shift`` in FLAT row-major order:
-    ``out.flat[p] = x.flat[(p + shift) % size]``.
+    ``out.flat[p] = x.flat[(p + shift) % size]``; leading axes (a stack of
+    state planes) are rolled alike, plane by plane.
 
     Decomposed into two row-rolls + two lane-rolls + a lane-mask select so
     every constituent op is a register-level vector rotate (the in-VMEM
-    analogue of the paper's intra-segment wrap, Alg. 5 line 10).
+    analogue of the paper's intra-segment wrap, Alg. 5 line 10).  The
+    rotates are ``pltpu.roll`` (``jnp.roll`` semantics, non-negative shift),
+    which Mosaic lowers for a traced shift; jitted so the same function
+    also runs outside a kernel.
     """
-    rows, lanes = x.shape
-    shift = jnp.asarray(shift) % (rows * lanes)
+    rows, lanes = x.shape[-2:]
+    row_ax, lane_ax = x.ndim - 2, x.ndim - 1
+    shift = jnp.asarray(shift, jnp.int32) % (rows * lanes)
     a = shift // lanes
     b = shift % lanes
-    hi = jnp.roll(x, -a, axis=0)  # rows shifted by floor(shift/lanes)
-    lo = jnp.roll(x, -(a + 1), axis=0)  # .. and one further for wrapped lanes
-    hi = jnp.roll(hi, -b, axis=1)
-    lo = jnp.roll(lo, -b, axis=1)
-    col = lax.broadcasted_iota(jnp.int32, (rows, lanes), 1)
-    return jnp.where(col < (lanes - b).astype(jnp.int32), hi, lo)
+    # hi[r] = x[(r + a) % rows], lo[r] = x[(r + a + 1) % rows]
+    hi = pltpu.roll(x, (rows - a) % rows, row_ax)
+    lo = pltpu.roll(x, rows - 1 - a, row_ax)
+    hi = pltpu.roll(hi, (lanes - b) % lanes, lane_ax)
+    lo = pltpu.roll(lo, (lanes - b) % lanes, lane_ax)
+    col = lax.broadcasted_iota(jnp.int32, x.shape, lane_ax)
+    return jnp.where(col < lanes - b, hi, lo)
 
 
 def key_to_seed(key) -> jnp.ndarray:
     """Derive a uint32 seed from a JAX PRNG key (stable, documented)."""
-    import jax
-
     data = jax.random.key_data(key).astype(jnp.uint32)
     return murmur3_fmix(data[..., 0] ^ (data[..., 1] * _GOLDEN))

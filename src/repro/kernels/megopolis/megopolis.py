@@ -16,9 +16,12 @@ Memory-access contract (DESIGN.md §2):
     (no CURAND state loads/stores — beyond-paper win, see EXPERIMENTS.md §Perf);
   * the current ancestor's weight ``w[k]`` is carried by VALUE in a VMEM
     scratch accumulator (never re-fetched), exactly like the register-carried
-    ``w_k`` in the CUDA original.
+    ``w_k`` in the CUDA original; the fused kernels carry the ancestor's
+    state the same way (DESIGN.md §11).
 
-Validated in ``interpret=True`` mode bit-exactly against ``ref.py``.
+Validated in ``interpret=True`` mode bit-exactly against ``ref.py``, and
+compiled by Mosaic for the TPU (``tests/test_tpu_compile.py``,
+``chip_smoke.py``).
 """
 
 from __future__ import annotations
@@ -33,7 +36,6 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.common import (
     TILE,
     flat_roll,
-    gather_state,
     hash_uniform,
     step_select,
     step_stats,
@@ -48,10 +50,11 @@ SEG = TILE  # 1024 particles = one (8,128) f32 tile
 def _sweep(t, b, o, seed, w_own, w_cmp, k_prev, wk_prev, n_total):
     """One accept/reject sweep of one (8,128) tile (Alg. 5 lines 5-14).
 
-    Shared verbatim by the single-bank and batched kernel bodies so the two
-    can never drift arithmetically; ``k_prev``/``wk_prev`` are the carried
-    ancestor/weight values (ignored at b == 0, where k <- i and w[k] is
-    seeded from the tile's own weights)."""
+    Shared verbatim by every kernel body so they can never drift
+    arithmetically; ``k_prev``/``wk_prev`` are the carried ancestor/weight
+    values (ignored at b == 0, where k <- i and w[k] is seeded from the
+    tile's own weights).  Returns ``(k, w[k], accept)``; the fused bodies
+    select the carried state with the same ``accept``."""
     i_global = tile_lane_ids(t)  # particle index (Alg. 5 line 5)
 
     k = jnp.where(b == 0, i_global, k_prev)  # k <- i      (Alg. 5 line 6)
@@ -66,7 +69,68 @@ def _sweep(t, b, o, seed, w_own, w_cmp, k_prev, wk_prev, n_total):
 
     u = hash_uniform(seed, i_global, b, dtype=w_j.dtype)
     accept = u * wk <= w_j  # u <= w[j]/w[k]  (line 13)
-    return jnp.where(accept, j_global, k), jnp.where(accept, w_j, wk)
+    return jnp.where(accept, j_global, k), jnp.where(accept, w_j, wk), accept
+
+
+def _carry_dtype(dtype):
+    """Dtype of the by-value state carry: f32 for float planes (compressed
+    bf16/f16 words widen exactly and narrow back unchanged), the plane's
+    own dtype for integer state."""
+    return jnp.float32 if jnp.issubdtype(dtype, jnp.floating) else dtype
+
+
+def _state_index(index):
+    """Block index map of a ``[d_pad, 8, 128]`` state block that follows the
+    weights' map ``index``: the same tile, the plane axis whole."""
+    return lambda *args: (0,) + index(*args)
+
+
+def _state_index_rows(index):
+    """``_state_index`` for a bank: ``(s, t, 0)`` becomes ``(s, 0, t, 0)``."""
+    def state(*args):
+        s, t, _ = index(*args)
+        return s, 0, t, 0
+    return state
+
+
+def _carry_state(b, o, accept, x_own, x_cmp, xk_prev):
+    """The ancestor's state, carried by VALUE like ``w[k]`` (DESIGN.md §11).
+
+    ``x_own``/``x_cmp``: this tile's and the comparison tile's
+    ``[d_pad, 8, 128]`` state blocks, fetched with the same block index
+    maps as the weights; each plane is flat-rolled by the same intra-
+    segment shift as ``w_cmp``, so lane p of the rolled block is the state
+    of ``j_global[p]`` and ``accept`` selects it exactly where ``k`` takes
+    ``j``.  Every state load is one aligned tile: no gather."""
+    xk = jnp.where(b == 0, x_own, xk_prev)
+    return jnp.where(accept[None], flat_roll(x_cmp, o % SEG), xk)
+
+
+def _prelude(lw, thr, n_total, st_ref, stats_ref, row):
+    """Fused-step prelude: reduce the resident log-weight block to the step
+    statistics, latch ``(m, do, deg)`` into SMEM and write the stats row."""
+    m, ess_norm, incr, maxw, deg = step_stats(lw, n_total)
+    do = ess_norm < thr
+    one, zero = jnp.float32(1.0), jnp.float32(0.0)
+    st_ref[0] = m
+    st_ref[1] = jnp.where(do, one, zero)
+    st_ref[2] = jnp.where(deg, one, zero)
+    stats_ref[row + (0,)] = ess_norm
+    stats_ref[row + (1,)] = jnp.where(do, incr, zero)
+    stats_ref[row + (2,)] = jnp.where(do, one, zero)
+    stats_ref[row + (3,)] = maxw
+
+
+def _step_weights(lw_own, lw_cmp, m, deg, n_total, plane):
+    """Normalised weights of the two tiles, as the composed path sees them:
+    ``exp(lw - m)``, the §16 uniform bank where the degenerate latch is
+    set, re-landed on the plane-dtype grid (a no-op at f32)."""
+    def norm(lw):
+        w = jnp.exp(lw.astype(jnp.float32) - m)
+        w = jnp.where(deg, jnp.float32(1.0 / n_total), w)
+        return w.astype(plane).astype(jnp.float32)
+
+    return norm(lw_own), norm(lw_cmp)
 
 
 def _kernel(offsets_ref, seed_ref, w_own_ref, w_cmp_ref, k_ref, wk_ref):
@@ -74,7 +138,7 @@ def _kernel(offsets_ref, seed_ref, w_own_ref, w_cmp_ref, k_ref, wk_ref):
     t = pl.program_id(0)
     b = pl.program_id(1)
     n_total = pl.num_programs(0) * SEG
-    k_new, wk_new = _sweep(
+    k_new, wk_new, _ = _sweep(
         t, b, offsets_ref[b], seed_ref[0],
         w_own_ref[...].astype(jnp.float32), w_cmp_ref[...].astype(jnp.float32),
         k_ref[...], wk_ref[...], n_total,
@@ -94,7 +158,7 @@ def _kernel_batch(offsets_ref, seeds_ref, w_own_ref, w_cmp_ref, k_ref, wk_ref):
     t = pl.program_id(1)
     b = pl.program_id(2)
     n_total = pl.num_programs(1) * SEG
-    k_new, wk_new = _sweep(
+    k_new, wk_new, _ = _sweep(
         t, b, offsets_ref[b], seeds_ref[s],
         w_own_ref[0].astype(jnp.float32), w_cmp_ref[0].astype(jnp.float32),
         k_ref[0], wk_ref[...], n_total,
@@ -103,31 +167,36 @@ def _kernel_batch(offsets_ref, seeds_ref, w_own_ref, w_cmp_ref, k_ref, wk_ref):
     wk_ref[...] = wk_new
 
 
-def _kernel_fused(offsets_ref, seed_ref, w_own_ref, w_cmp_ref, planes_ref,
-                  k_ref, out_ref, wk_ref):
-    """Fused resample+gather grid step (t, b): the Alg. 5 sweep, then — at
-    the LAST iteration only — the ancestor's state tile is copied from the
-    resident plane stack straight to the output ref (DESIGN.md §11).  The
+def _kernel_fused(offsets_ref, seed_ref, w_own_ref, w_cmp_ref, x_own_ref,
+                  x_cmp_ref, k_ref, out_ref, wk_ref, xk_ref):
+    """Fused resample+gather grid step (t, b): the Alg. 5 sweep with the
+    ancestor's state carried by value beside ``w[k]`` (DESIGN.md §11); the
+    LAST iteration writes the carried state tile to the output.  The
     ancestor index never round-trips through HBM between selection and
-    copy; it is the VMEM carry ``k_ref`` itself."""
+    copy."""
     t = pl.program_id(0)
     b = pl.program_id(1)
+    o = offsets_ref[b]
     n_total = pl.num_programs(0) * SEG
-    k_new, wk_new = _sweep(
-        t, b, offsets_ref[b], seed_ref[0],
+    k_new, wk_new, accept = _sweep(
+        t, b, o, seed_ref[0],
         w_own_ref[...].astype(jnp.float32), w_cmp_ref[...].astype(jnp.float32),
         k_ref[...], wk_ref[...], n_total,
     )
     k_ref[...] = k_new
     wk_ref[...] = wk_new
+    cd = xk_ref.dtype
+    xk_new = _carry_state(b, o, accept, x_own_ref[...].astype(cd),
+                          x_cmp_ref[...].astype(cd), xk_ref[...])
+    xk_ref[...] = xk_new
 
     @pl.when(b == pl.num_programs(1) - 1)
     def _copy_state():
-        out_ref[...] = gather_state(planes_ref[...], k_new)
+        out_ref[...] = xk_new.astype(out_ref.dtype)
 
 
 def _kernel_fused_rows(offsets_ref, seeds_ref, w_own_ref, w_cmp_ref,
-                       planes_ref, k_ref, out_ref, wk_ref):
+                       x_own_ref, x_cmp_ref, k_ref, out_ref, wk_ref, xk_ref):
     """Fused grid step (s, t, b) over a bank: per-row offset TABLE rows
     ``offsets[s]`` + per-row seed, so row s is bit-identical to the fused
     single kernel with that row's table (passing identical rows recovers
@@ -135,23 +204,28 @@ def _kernel_fused_rows(offsets_ref, seeds_ref, w_own_ref, w_cmp_ref,
     s = pl.program_id(0)
     t = pl.program_id(1)
     b = pl.program_id(2)
+    o = offsets_ref[s, b]
     n_total = pl.num_programs(1) * SEG
-    k_new, wk_new = _sweep(
-        t, b, offsets_ref[s, b], seeds_ref[s],
+    k_new, wk_new, accept = _sweep(
+        t, b, o, seeds_ref[s],
         w_own_ref[0].astype(jnp.float32), w_cmp_ref[0].astype(jnp.float32),
         k_ref[0], wk_ref[...], n_total,
     )
     k_ref[0] = k_new
     wk_ref[...] = wk_new
+    cd = xk_ref.dtype
+    xk_new = _carry_state(b, o, accept, x_own_ref[0].astype(cd),
+                          x_cmp_ref[0].astype(cd), xk_ref[...])
+    xk_ref[...] = xk_new
 
     @pl.when(b == pl.num_programs(2) - 1)
     def _copy_state():
-        out_ref[0] = gather_state(planes_ref[0], k_new)
+        out_ref[0] = xk_new.astype(out_ref.dtype)
 
 
 def _kernel_step(offsets_ref, seed_ref, thr_ref, lw_own_ref, lw_cmp_ref,
-                 lw_full_ref, planes_ref, k_ref, out_ref, stats_ref,
-                 wk_ref, st_ref):
+                 lw_full_ref, x_own_ref, x_cmp_ref, k_ref, out_ref, stats_ref,
+                 wk_ref, xk_ref, st_ref):
     """Fused STEP grid step (t, b): the whole SMC resample decision on-chip.
 
     At (0, 0) a prelude reduces the resident log-weight array to the step
@@ -159,55 +233,41 @@ def _kernel_step(offsets_ref, seed_ref, thr_ref, lw_own_ref, lw_cmp_ref,
     increment) and latches the resample decision ``ess_norm < threshold``
     into SMEM scratch.  Every sweep then runs on ``exp(lw - m)`` — the SAME
     normalised weights the composed path hands to ``apply`` — and the last
-    iteration's epilogue either commits the selected ancestors or the
-    identity permutation (state copy becomes a self-gather no-op)."""
+    iteration's epilogue either commits the selected ancestors and carried
+    state or the identity permutation and the tile's own state."""
     t = pl.program_id(0)
     b = pl.program_id(1)
+    o = offsets_ref[b]
     n_total = pl.num_programs(0) * SEG
 
     @pl.when((t == 0) & (b == 0))
-    def _prelude():
-        m, ess_norm, incr, maxw, deg = step_stats(
-            lw_full_ref[...].astype(jnp.float32).reshape(n_total), n_total)
-        do = ess_norm < thr_ref[0]
-        st_ref[0] = m
-        st_ref[1] = jnp.where(do, jnp.float32(1.0), jnp.float32(0.0))
-        st_ref[2] = jnp.where(deg, jnp.float32(1.0), jnp.float32(0.0))
-        stats_ref[0] = ess_norm
-        stats_ref[1] = jnp.where(do, incr, jnp.float32(0.0))
-        stats_ref[2] = jnp.where(do, jnp.float32(1.0), jnp.float32(0.0))
-        stats_ref[3] = maxw
+    def _():
+        _prelude(lw_full_ref[...].astype(jnp.float32), thr_ref[0], n_total,
+                 st_ref, stats_ref, ())
 
-    m = st_ref[0]
     do = st_ref[1] > 0.5
-    deg = st_ref[2] > 0.5
-    # Normalised weights re-land on the plane-dtype grid (the composed path
-    # quantises at the public ``apply`` boundary); a no-op at f32.  The §16
-    # degenerate latch substitutes the uniform bank BEFORE the requantise —
-    # the same value ``normalise_log_weights`` hands the composed path.
-    w_own = jnp.exp(lw_own_ref[...].astype(jnp.float32) - m)
-    w_cmp = jnp.exp(lw_cmp_ref[...].astype(jnp.float32) - m)
-    w_own = jnp.where(deg, jnp.float32(1.0 / n_total), w_own)
-    w_cmp = jnp.where(deg, jnp.float32(1.0 / n_total), w_cmp)
-    w_own = w_own.astype(lw_own_ref.dtype).astype(jnp.float32)
-    w_cmp = w_cmp.astype(lw_cmp_ref.dtype).astype(jnp.float32)
-    k_new, wk_new = _sweep(
-        t, b, offsets_ref[b], seed_ref[0],
-        w_own, w_cmp, k_ref[...], wk_ref[...], n_total,
+    w_own, w_cmp = _step_weights(lw_own_ref[...], lw_cmp_ref[...], st_ref[0],
+                                 st_ref[2] > 0.5, n_total, lw_own_ref.dtype)
+    k_new, wk_new, accept = _sweep(
+        t, b, o, seed_ref[0], w_own, w_cmp, k_ref[...], wk_ref[...], n_total,
     )
     k_ref[...] = k_new
     wk_ref[...] = wk_new
+    cd = xk_ref.dtype
+    x_own = x_own_ref[...].astype(cd)
+    xk_new = _carry_state(b, o, accept, x_own, x_cmp_ref[...].astype(cd),
+                          xk_ref[...])
+    xk_ref[...] = xk_new
 
     @pl.when(b == pl.num_programs(1) - 1)
     def _commit():
-        k_sel = step_select(do, k_new, t)
-        k_ref[...] = k_sel
-        out_ref[...] = gather_state(planes_ref[...], k_sel)
+        k_ref[...] = step_select(do, k_new, t)
+        out_ref[...] = jnp.where(do, xk_new, x_own).astype(out_ref.dtype)
 
 
 def _kernel_step_rows(offsets_ref, seeds_ref, thr_ref, lw_own_ref, lw_cmp_ref,
-                      lw_full_ref, planes_ref, k_ref, out_ref, stats_ref,
-                      wk_ref, st_ref):
+                      lw_full_ref, x_own_ref, x_cmp_ref, k_ref, out_ref,
+                      stats_ref, wk_ref, xk_ref, st_ref):
     """Fused STEP over a bank, grid (s, t, b): per-row offset tables and
     seeds as in ``_kernel_fused_rows``; the prelude re-runs at each row's
     (t, b) == (0, 0) so the SMEM (m, do) latch and the per-row stats row
@@ -215,42 +275,32 @@ def _kernel_step_rows(offsets_ref, seeds_ref, thr_ref, lw_own_ref, lw_cmp_ref,
     s = pl.program_id(0)
     t = pl.program_id(1)
     b = pl.program_id(2)
+    o = offsets_ref[s, b]
     n_total = pl.num_programs(1) * SEG
 
     @pl.when((t == 0) & (b == 0))
-    def _prelude():
-        m, ess_norm, incr, maxw, deg = step_stats(
-            lw_full_ref[0].astype(jnp.float32).reshape(n_total), n_total)
-        do = ess_norm < thr_ref[0]
-        st_ref[0] = m
-        st_ref[1] = jnp.where(do, jnp.float32(1.0), jnp.float32(0.0))
-        st_ref[2] = jnp.where(deg, jnp.float32(1.0), jnp.float32(0.0))
-        stats_ref[s, 0] = ess_norm
-        stats_ref[s, 1] = jnp.where(do, incr, jnp.float32(0.0))
-        stats_ref[s, 2] = jnp.where(do, jnp.float32(1.0), jnp.float32(0.0))
-        stats_ref[s, 3] = maxw
+    def _():
+        _prelude(lw_full_ref[0].astype(jnp.float32), thr_ref[0], n_total,
+                 st_ref, stats_ref, (s,))
 
-    m = st_ref[0]
     do = st_ref[1] > 0.5
-    deg = st_ref[2] > 0.5
-    w_own = jnp.exp(lw_own_ref[0].astype(jnp.float32) - m)
-    w_cmp = jnp.exp(lw_cmp_ref[0].astype(jnp.float32) - m)
-    w_own = jnp.where(deg, jnp.float32(1.0 / n_total), w_own)
-    w_cmp = jnp.where(deg, jnp.float32(1.0 / n_total), w_cmp)
-    w_own = w_own.astype(lw_own_ref.dtype).astype(jnp.float32)
-    w_cmp = w_cmp.astype(lw_cmp_ref.dtype).astype(jnp.float32)
-    k_new, wk_new = _sweep(
-        t, b, offsets_ref[s, b], seeds_ref[s],
-        w_own, w_cmp, k_ref[0], wk_ref[...], n_total,
+    w_own, w_cmp = _step_weights(lw_own_ref[0], lw_cmp_ref[0], st_ref[0],
+                                 st_ref[2] > 0.5, n_total, lw_own_ref.dtype)
+    k_new, wk_new, accept = _sweep(
+        t, b, o, seeds_ref[s], w_own, w_cmp, k_ref[0], wk_ref[...], n_total,
     )
     k_ref[0] = k_new
     wk_ref[...] = wk_new
+    cd = xk_ref.dtype
+    x_own = x_own_ref[0].astype(cd)
+    xk_new = _carry_state(b, o, accept, x_own, x_cmp_ref[0].astype(cd),
+                          xk_ref[...])
+    xk_ref[...] = xk_new
 
     @pl.when(b == pl.num_programs(2) - 1)
     def _commit():
-        k_sel = step_select(do, k_new, t)
-        k_ref[0] = k_sel
-        out_ref[0] = gather_state(planes_ref[0], k_sel)
+        k_ref[0] = step_select(do, k_new, t)
+        out_ref[0] = jnp.where(do, xk_new, x_own).astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("num_iters", "interpret"))
@@ -260,7 +310,7 @@ def megopolis_pallas(
     seed: jnp.ndarray,
     *,
     num_iters: int,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Raw pallas_call. ``weights2d``: f32[R, 128] with R % 8 == 0;
     ``offsets``: int32[B]; ``seed``: uint32[1].  Returns int32[R, 128]."""
@@ -298,7 +348,7 @@ def megopolis_pallas_batch(
     seeds: jnp.ndarray,
     *,
     num_iters: int,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Batched pallas_call: a whole ``[Bz, R, 128]`` weight bank in ONE launch.
 
@@ -349,10 +399,11 @@ def megopolis_pallas_fused(
     seed: jnp.ndarray,
     *,
     num_iters: int,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Fused resample+gather pallas_call (DESIGN.md §11).  ``planes``:
-    particle state as a ``[d_pad, R, 128]`` plane stack (VMEM-resident);
+    particle state as a ``[d_pad, R, 128]`` plane stack, streamed tile by
+    tile beside the weights (own and comparison blocks);
     other arguments as for ``megopolis_pallas``.  Returns ``(ancestors
     int32[R, 128], state [d_pad, R, 128])`` — the ancestor stream is
     identical to the unfused kernel's (same sweep arithmetic, same RNG)."""
@@ -365,21 +416,27 @@ def megopolis_pallas_fused(
     def _cmp_index(t, b, offs, seed):
         return (t + offs[b] // SEG) % num_tiles, 0
 
+    def _own_index(t, b, offs, seed):
+        return t, 0
+
+    state_block = (d_pad, SUBLANES, LANES)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(num_tiles, num_iters),
         in_specs=[
-            pl.BlockSpec((SUBLANES, LANES), lambda t, b, offs, seed: (t, 0)),
+            pl.BlockSpec((SUBLANES, LANES), _own_index),
             pl.BlockSpec((SUBLANES, LANES), _cmp_index),
-            # whole state plane stack resident; block index constant in
-            # (t, b) -> fetched once per launch
-            pl.BlockSpec((d_pad, rows, LANES), lambda t, b, offs, seed: (0, 0, 0)),
+            pl.BlockSpec(state_block, _state_index(_own_index)),
+            pl.BlockSpec(state_block, _state_index(_cmp_index)),
         ],
         out_specs=[
-            pl.BlockSpec((SUBLANES, LANES), lambda t, b, offs, seed: (t, 0)),
-            pl.BlockSpec((d_pad, SUBLANES, LANES), lambda t, b, offs, seed: (0, t, 0)),
+            pl.BlockSpec((SUBLANES, LANES), _own_index),
+            pl.BlockSpec(state_block, _state_index(_own_index)),
         ],
-        scratch_shapes=[pltpu.VMEM((SUBLANES, LANES), jnp.float32)],
+        scratch_shapes=[
+            pltpu.VMEM((SUBLANES, LANES), jnp.float32),
+            pltpu.VMEM(state_block, _carry_dtype(planes.dtype)),
+        ],
     )
     return pl.pallas_call(
         _kernel_fused,
@@ -389,7 +446,7 @@ def megopolis_pallas_fused(
             jax.ShapeDtypeStruct((d_pad, rows, lanes), planes.dtype),
         ],
         interpret=interpret,
-    )(offsets, seed, weights2d, weights2d, planes)
+    )(offsets, seed, weights2d, weights2d, planes, planes)
 
 
 @functools.partial(jax.jit, static_argnames=("num_iters", "interpret"))
@@ -400,7 +457,7 @@ def megopolis_pallas_fused_rows(
     seeds: jnp.ndarray,
     *,
     num_iters: int,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Fused bank launch: grid (Bz, num_tiles, num_iters) with PER-ROW
     offset tables ``offsets2d`` int32[Bz, num_iters] and per-row seeds.
@@ -423,23 +480,24 @@ def megopolis_pallas_fused_rows(
     def _cmp_index(s, t, b, offs, seeds):
         return s, (t + offs[s, b] // SEG) % num_tiles, 0
 
+    state_block = (1, d_pad, SUBLANES, LANES)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(bsz, num_tiles, num_iters),
         in_specs=[
             pl.BlockSpec((1, SUBLANES, LANES), _own_index),
             pl.BlockSpec((1, SUBLANES, LANES), _cmp_index),
-            pl.BlockSpec(
-                (1, d_pad, rows, LANES), lambda s, t, b, offs, seeds: (s, 0, 0, 0)
-            ),
+            pl.BlockSpec(state_block, _state_index_rows(_own_index)),
+            pl.BlockSpec(state_block, _state_index_rows(_cmp_index)),
         ],
         out_specs=[
             pl.BlockSpec((1, SUBLANES, LANES), _own_index),
-            pl.BlockSpec(
-                (1, d_pad, SUBLANES, LANES), lambda s, t, b, offs, seeds: (s, 0, t, 0)
-            ),
+            pl.BlockSpec(state_block, _state_index_rows(_own_index)),
         ],
-        scratch_shapes=[pltpu.VMEM((SUBLANES, LANES), jnp.float32)],
+        scratch_shapes=[
+            pltpu.VMEM((SUBLANES, LANES), jnp.float32),
+            pltpu.VMEM(state_block[1:], _carry_dtype(planes4d.dtype)),
+        ],
     )
     return pl.pallas_call(
         _kernel_fused_rows,
@@ -449,7 +507,7 @@ def megopolis_pallas_fused_rows(
             jax.ShapeDtypeStruct((bsz, d_pad, rows, lanes), planes4d.dtype),
         ],
         interpret=interpret,
-    )(offsets2d, seeds, weights3d, weights3d, planes4d)
+    )(offsets2d, seeds, weights3d, weights3d, planes4d, planes4d)
 
 
 @functools.partial(jax.jit, static_argnames=("num_iters", "interpret"))
@@ -461,7 +519,7 @@ def megopolis_pallas_step(
     thr: jnp.ndarray,
     *,
     num_iters: int,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Fused SMC-step pallas_call (DESIGN.md §12): normalise → ESS →
     conditional resample → state copy, ONE launch.  ``log_weights2d``:
@@ -477,26 +535,32 @@ def megopolis_pallas_step(
     assert planes.shape[1:] == (rows, lanes)
     num_tiles = rows // SUBLANES
 
+    def _own_index(t, b, offs, seed, thr):
+        return t, 0
+
     def _cmp_index(t, b, offs, seed, thr):
         return (t + offs[b] // SEG) % num_tiles, 0
 
+    state_block = (d_pad, SUBLANES, LANES)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,  # offsets + seed + f32 ESS threshold
         grid=(num_tiles, num_iters),
         in_specs=[
-            pl.BlockSpec((SUBLANES, LANES), lambda t, b, o, s, r: (t, 0)),
+            pl.BlockSpec((SUBLANES, LANES), _own_index),
             pl.BlockSpec((SUBLANES, LANES), _cmp_index),
             # whole log-weight array resident for the (0,0) stats prelude
             pl.BlockSpec((rows, LANES), lambda t, b, o, s, r: (0, 0)),
-            pl.BlockSpec((d_pad, rows, LANES), lambda t, b, o, s, r: (0, 0, 0)),
+            pl.BlockSpec(state_block, _state_index(_own_index)),
+            pl.BlockSpec(state_block, _state_index(_cmp_index)),
         ],
         out_specs=[
-            pl.BlockSpec((SUBLANES, LANES), lambda t, b, o, s, r: (t, 0)),
-            pl.BlockSpec((d_pad, SUBLANES, LANES), lambda t, b, o, s, r: (0, t, 0)),
+            pl.BlockSpec((SUBLANES, LANES), _own_index),
+            pl.BlockSpec(state_block, _state_index(_own_index)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         scratch_shapes=[
             pltpu.VMEM((SUBLANES, LANES), jnp.float32),
+            pltpu.VMEM(state_block, _carry_dtype(planes.dtype)),
             pltpu.SMEM((3,), jnp.float32),  # (m, do, deg) latch across grid steps
         ],
     )
@@ -509,7 +573,8 @@ def megopolis_pallas_step(
             jax.ShapeDtypeStruct((4,), jnp.float32),
         ],
         interpret=interpret,
-    )(offsets, seed, thr, log_weights2d, log_weights2d, log_weights2d, planes)
+    )(offsets, seed, thr, log_weights2d, log_weights2d, log_weights2d, planes,
+      planes)
 
 
 @functools.partial(jax.jit, static_argnames=("num_iters", "interpret"))
@@ -521,7 +586,7 @@ def megopolis_pallas_step_rows(
     thr: jnp.ndarray,
     *,
     num_iters: int,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Fused SMC-step bank launch: row s is bit-identical to
     ``megopolis_pallas_step(log_weights3d[s], planes4d[s], offsets2d[s],
@@ -539,6 +604,7 @@ def megopolis_pallas_step_rows(
     def _cmp_index(s, t, b, offs, seeds, thr):
         return s, (t + offs[s, b] // SEG) % num_tiles, 0
 
+    state_block = (1, d_pad, SUBLANES, LANES)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(bsz, num_tiles, num_iters),
@@ -546,19 +612,17 @@ def megopolis_pallas_step_rows(
             pl.BlockSpec((1, SUBLANES, LANES), _own_index),
             pl.BlockSpec((1, SUBLANES, LANES), _cmp_index),
             pl.BlockSpec((1, rows, LANES), lambda s, t, b, o, se, r: (s, 0, 0)),
-            pl.BlockSpec(
-                (1, d_pad, rows, LANES), lambda s, t, b, o, se, r: (s, 0, 0, 0)
-            ),
+            pl.BlockSpec(state_block, _state_index_rows(_own_index)),
+            pl.BlockSpec(state_block, _state_index_rows(_cmp_index)),
         ],
         out_specs=[
             pl.BlockSpec((1, SUBLANES, LANES), _own_index),
-            pl.BlockSpec(
-                (1, d_pad, SUBLANES, LANES), lambda s, t, b, o, se, r: (s, 0, t, 0)
-            ),
+            pl.BlockSpec(state_block, _state_index_rows(_own_index)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         scratch_shapes=[
             pltpu.VMEM((SUBLANES, LANES), jnp.float32),
+            pltpu.VMEM(state_block[1:], _carry_dtype(planes4d.dtype)),
             pltpu.SMEM((3,), jnp.float32),
         ],
     )
@@ -571,4 +635,5 @@ def megopolis_pallas_step_rows(
             jax.ShapeDtypeStruct((bsz, 4), jnp.float32),
         ],
         interpret=interpret,
-    )(offsets2d, seeds, thr, log_weights3d, log_weights3d, log_weights3d, planes4d)
+    )(offsets2d, seeds, thr, log_weights3d, log_weights3d, log_weights3d,
+      planes4d, planes4d)

@@ -15,7 +15,6 @@ import jax.numpy as jnp
 
 from repro.kernels.common import (
     TILE,
-    check_state_resident,
     check_vmem_resident,
     compress_plane,
     key_to_seed,
@@ -24,7 +23,6 @@ from repro.kernels.common import (
     run_fused_bank,
     run_step_bank,
     state_dim_of,
-    state_itemsize,
     unpack_state_planes,
 )
 from repro.kernels.megopolis.megopolis import (
@@ -43,13 +41,14 @@ def megopolis_tpu(
     weights: jnp.ndarray,
     num_iters: int,
     *,
-    interpret: bool = True,
+    interpret: bool,
     plane_dtype="float32",
 ) -> jnp.ndarray:
     """Resample with the Pallas Megopolis kernel; returns int32[N] ancestors.
 
-    ``interpret=True`` (default here) runs the kernel body on CPU for
-    validation; pass ``interpret=False`` on real TPU hardware.
+    ``interpret=True`` runs the kernel body through the Pallas interpreter
+    (CPU validation); ``interpret=False`` compiles it with Mosaic for the
+    TPU.  There is no default: every caller names the mode it runs.
     """
     n = weights.shape[0]
     if n % TILE != 0:
@@ -70,7 +69,7 @@ def megopolis_tpu_batch(
     weights: jnp.ndarray,
     num_iters: int,
     *,
-    interpret: bool = True,
+    interpret: bool,
     plane_dtype="float32",
 ) -> jnp.ndarray:
     """Resample a ``[B, N]`` weight bank in one kernel launch (DESIGN.md §4).
@@ -102,21 +101,20 @@ def megopolis_tpu_apply(
     particles: jnp.ndarray,
     num_iters: int,
     *,
-    interpret: bool = True,
+    interpret: bool,
     plane_dtype="float32",
 ):
     """Fused resample+gather (DESIGN.md §11): ONE kernel launch selects the
-    ancestors (identical stream to ``megopolis_tpu``) and copies each
-    ancestor's state tile in VMEM.  ``particles``: ``[N]`` or ``[N, ...]``
-    (trailing dims are the state).  Returns ``(particles' , ancestors)``."""
+    ancestors (identical stream to ``megopolis_tpu``) and carries each
+    ancestor's state by value beside its weight, so no state cap applies.
+    ``particles``: ``[N]`` or ``[N, ...]`` (trailing dims are the state).
+    Returns ``(particles' , ancestors)``."""
     n = weights.shape[0]
     if n % TILE != 0:
         raise ValueError(
             f"megopolis_tpu_apply requires N % {TILE} == 0 (one f32 VMEM tile); got N={n}."
         )
-    check_state_resident(n, state_dim_of(particles, n, "megopolis_tpu_apply"),
-                         "megopolis_tpu_apply",
-                         itemsize=state_itemsize(particles, plane_dtype))
+    state_dim_of(particles, n, "megopolis_tpu_apply")  # validates the particle axis
     key_off, key_seed = jax.random.split(key)
     offsets = jax.random.randint(key_off, (num_iters,), 0, n, dtype=jnp.int32)
     seed = key_to_seed(key_seed).reshape(1)
@@ -136,7 +134,7 @@ def megopolis_tpu_apply_batch(
     particles: jnp.ndarray,
     num_iters: int,
     *,
-    interpret: bool = True,
+    interpret: bool,
     plane_dtype="float32",
 ):
     """Fused bank launch under the ``megopolis_tpu_batch`` contract: the
@@ -168,7 +166,7 @@ def megopolis_tpu_apply_rows(
     particles: jnp.ndarray,
     num_iters: int,
     *,
-    interpret: bool = True,
+    interpret: bool,
     plane_dtype="float32",
 ):
     """Fused bank launch over EXPLICIT per-row keys (the filter-bank path):
@@ -202,7 +200,7 @@ def _apply_rows_launch(weights, particles, offsets2d, seeds, *, num_iters,
         lambda w3, planes: megopolis_pallas_fused_rows(
             w3, planes, offsets2d, seeds, num_iters=num_iters, interpret=interpret
         ),
-        weights, particles, who, plane_dtype=plane_dtype,
+        weights, particles, who, plane_dtype=plane_dtype, state_resident=False,
     )
 
 
@@ -213,7 +211,7 @@ def megopolis_tpu_step(
     num_iters: int,
     ess_threshold,
     *,
-    interpret: bool = True,
+    interpret: bool,
     plane_dtype="float32",
 ):
     """Fused SMC step (DESIGN.md §12): normalise → ESS → conditional
@@ -232,9 +230,7 @@ def megopolis_tpu_step(
                         remedy="Compose Resampler.step on the reference/xla backend "
                                "above this size.",
                         itemsize=plane_itemsize(plane_dtype))
-    check_state_resident(n, state_dim_of(particles, n, "megopolis_tpu_step"),
-                         "megopolis_tpu_step",
-                         itemsize=state_itemsize(particles, plane_dtype))
+    state_dim_of(particles, n, "megopolis_tpu_step")  # validates the particle axis
     key_off, key_seed = jax.random.split(key)
     offsets = jax.random.randint(key_off, (num_iters,), 0, n, dtype=jnp.int32)
     seed = key_to_seed(key_seed).reshape(1)
@@ -256,7 +252,7 @@ def megopolis_tpu_step_rows(
     num_iters: int,
     ess_threshold,
     *,
-    interpret: bool = True,
+    interpret: bool,
     plane_dtype="float32",
 ):
     """Fused SMC-step bank over EXPLICIT per-row keys: row b is
@@ -290,5 +286,5 @@ def megopolis_tpu_step_rows(
             interpret=interpret
         ),
         log_weights, particles, "megopolis_tpu_step_rows",
-        plane_dtype=plane_dtype,
+        plane_dtype=plane_dtype, state_resident=False,
     )

@@ -164,7 +164,7 @@ def _make_kernel_step(p_at):
         @pl.when((t == 0) & (b == 0))
         def _prelude():
             m, ess_norm, incr, maxw, deg = step_stats(
-                lw_full_ref[...].astype(jnp.float32).reshape(n_total), n_total
+                lw_full_ref[...].astype(jnp.float32), n_total
             )
             do = ess_norm < thr_ref[0]
             st_ref[0] = m
@@ -254,7 +254,7 @@ def metropolis_c1_pallas_step(
     thr: jnp.ndarray,
     *,
     num_iters: int,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Fused C1 SMC step: normalise → ESS → conditional Alg. 3 resample →
     state copy, ONE launch.  Returns ``(int32[R, 128], [d_pad, R, 128],
@@ -277,7 +277,7 @@ def metropolis_c2_pallas_step(
     thr: jnp.ndarray,
     *,
     num_iters: int,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Fused C2 SMC step: as C1 but with a fresh partition per (t, b)
     (Alg. 4).  Returns ``(int32[R, 128], [d_pad, R, 128], f32[4])``."""
@@ -333,7 +333,7 @@ def metropolis_c1_pallas_fused(
     seed: jnp.ndarray,
     *,
     num_iters: int,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Fused C1: ancestors identical to ``metropolis_c1_pallas``; returns
     ``(int32[R, 128], [d_pad, R, 128])``."""
@@ -353,7 +353,7 @@ def metropolis_c2_pallas_fused(
     seed: jnp.ndarray,
     *,
     num_iters: int,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Fused C2: ancestors identical to ``metropolis_c2_pallas``; returns
     ``(int32[R, 128], [d_pad, R, 128])``."""
@@ -372,7 +372,7 @@ def metropolis_c1_pallas(
     seed: jnp.ndarray,
     *,
     num_iters: int,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """``weights2d``: f32[R, 128] with R % 8 == 0; ``partitions``:
     int32[num_tiles] (one fixed partition tile per own-tile); ``seed``:
@@ -408,7 +408,7 @@ def metropolis_c2_pallas(
     seed: jnp.ndarray,
     *,
     num_iters: int,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """``partitions``: int32[num_tiles * num_iters], row-major by tile —
     ``partitions[t * num_iters + b]`` is tile t's partition at iteration b

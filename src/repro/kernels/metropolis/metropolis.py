@@ -134,7 +134,7 @@ def _kernel_step(seed_ref, thr_ref, lw_full_ref, lw_own_ref, planes_ref,
     @pl.when((t == 0) & (b == 0))
     def _prelude():
         m, ess_norm, incr, maxw, deg = step_stats(
-            lw_full_ref[...].astype(jnp.float32).reshape(n_total), n_total)
+            lw_full_ref[...].astype(jnp.float32), n_total)
         do = ess_norm < thr_ref[0]
         st_ref[0] = m
         st_ref[1] = jnp.where(do, jnp.float32(1.0), jnp.float32(0.0))
@@ -182,7 +182,7 @@ def _kernel_step_rows(seeds_ref, thr_ref, lw_full_ref, lw_own_ref, planes_ref,
     @pl.when((t == 0) & (b == 0))
     def _prelude():
         m, ess_norm, incr, maxw, deg = step_stats(
-            lw_full_ref[0].astype(jnp.float32).reshape(n_total), n_total)
+            lw_full_ref[0].astype(jnp.float32), n_total)
         do = ess_norm < thr_ref[0]
         st_ref[0] = m
         st_ref[1] = jnp.where(do, jnp.float32(1.0), jnp.float32(0.0))
@@ -220,7 +220,7 @@ def metropolis_pallas(
     seed: jnp.ndarray,
     *,
     num_iters: int,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     rows, lanes = weights2d.shape
     assert lanes == LANES and rows % SUBLANES == 0
@@ -251,7 +251,7 @@ def metropolis_pallas_batch(
     seeds: jnp.ndarray,
     *,
     num_iters: int,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Batched pallas_call: a ``[Bz, R, 128]`` weight bank in ONE launch.
 
@@ -292,7 +292,7 @@ def metropolis_pallas_fused(
     seed: jnp.ndarray,
     *,
     num_iters: int,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Fused resample+gather pallas_call: ancestors identical to
     ``metropolis_pallas``; ``planes`` ``[d_pad, R, 128]`` resident.  Returns
@@ -335,7 +335,7 @@ def metropolis_pallas_fused_batch(
     seeds: jnp.ndarray,
     *,
     num_iters: int,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Fused bank launch: one leading-batch-grid pallas_call; row s is
     bit-identical to ``metropolis_pallas_fused(weights3d[s], planes4d[s],
@@ -383,7 +383,7 @@ def metropolis_pallas_step(
     thr: jnp.ndarray,
     *,
     num_iters: int,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Fused SMC-step pallas_call: normalise → ESS → conditional Alg. 2
     resample → state copy, ONE launch.  ``log_weights2d``: f32[R, 128]
@@ -435,7 +435,7 @@ def metropolis_pallas_step_rows(
     thr: jnp.ndarray,
     *,
     num_iters: int,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Fused SMC-step bank launch: row s is bit-identical to
     ``metropolis_pallas_step(log_weights3d[s], planes4d[s], seeds[s:s+1],

@@ -54,7 +54,7 @@ def metropolis_tpu(
     weights: jnp.ndarray,
     num_iters: int,
     *,
-    interpret: bool = True,
+    interpret: bool,
     plane_dtype="float32",
 ) -> jnp.ndarray:
     n = weights.shape[0]
@@ -71,7 +71,7 @@ def metropolis_tpu_batch(
     weights: jnp.ndarray,
     num_iters: int,
     *,
-    interpret: bool = True,
+    interpret: bool,
     plane_dtype="float32",
 ) -> jnp.ndarray:
     """One ``[B, R, 128]`` launch; row b == ``metropolis_tpu(split(key,B)[b],
@@ -107,7 +107,7 @@ def metropolis_tpu_apply(
     particles: jnp.ndarray,
     num_iters: int,
     *,
-    interpret: bool = True,
+    interpret: bool,
     plane_dtype="float32",
 ):
     """Fused resample+gather (DESIGN.md §11): ancestors identical to
@@ -143,7 +143,7 @@ def metropolis_tpu_apply_batch(
     particles: jnp.ndarray,
     num_iters: int,
     *,
-    interpret: bool = True,
+    interpret: bool,
     plane_dtype="float32",
 ):
     """Fused ``[B, R, 128]`` bank launch under the §4 split-key contract;
@@ -165,7 +165,7 @@ def metropolis_tpu_apply_rows(
     particles: jnp.ndarray,
     num_iters: int,
     *,
-    interpret: bool = True,
+    interpret: bool,
     plane_dtype="float32",
 ):
     """Fused bank launch over EXPLICIT per-row keys (the filter-bank path);
@@ -188,7 +188,7 @@ def metropolis_tpu_step(
     num_iters: int,
     ess_threshold,
     *,
-    interpret: bool = True,
+    interpret: bool,
     plane_dtype="float32",
 ):
     """Fused SMC step (DESIGN.md §12): normalise → ESS → conditional Alg. 2
@@ -215,7 +215,7 @@ def metropolis_tpu_step_rows(
     num_iters: int,
     ess_threshold,
     *,
-    interpret: bool = True,
+    interpret: bool,
     plane_dtype="float32",
 ):
     """Fused SMC-step bank over EXPLICIT per-row keys; row b ==
@@ -245,7 +245,7 @@ def metropolis_c1_tpu(
     weights: jnp.ndarray,
     num_iters: int,
     *,
-    interpret: bool = True,
+    interpret: bool,
     plane_dtype="float32",
 ) -> jnp.ndarray:
     """Alg. 3 at tile granularity: ONE partition tile per own-tile, kept for
@@ -268,7 +268,7 @@ def metropolis_c2_tpu(
     weights: jnp.ndarray,
     num_iters: int,
     *,
-    interpret: bool = True,
+    interpret: bool,
     plane_dtype="float32",
 ) -> jnp.ndarray:
     """Alg. 4 at tile granularity: a FRESH partition tile per (tile,
@@ -292,7 +292,7 @@ def metropolis_c1_tpu_apply(
     particles: jnp.ndarray,
     num_iters: int,
     *,
-    interpret: bool = True,
+    interpret: bool,
     plane_dtype="float32",
 ):
     """Fused C1 resample+gather; same key split as ``metropolis_c1_tpu``.
@@ -318,7 +318,7 @@ def metropolis_c2_tpu_apply(
     particles: jnp.ndarray,
     num_iters: int,
     *,
-    interpret: bool = True,
+    interpret: bool,
     plane_dtype="float32",
 ):
     """Fused C2 resample+gather; same key split as ``metropolis_c2_tpu``.
@@ -347,7 +347,7 @@ def metropolis_c1_tpu_step(
     num_iters: int,
     ess_threshold,
     *,
-    interpret: bool = True,
+    interpret: bool,
     plane_dtype="float32",
 ):
     """Fused C1 SMC step; same key split as ``metropolis_c1_tpu``.  Unlike
@@ -376,7 +376,7 @@ def metropolis_c2_tpu_step(
     num_iters: int,
     ess_threshold,
     *,
-    interpret: bool = True,
+    interpret: bool,
     plane_dtype="float32",
 ):
     """Fused C2 SMC step; same key split as ``metropolis_c2_tpu``; the

@@ -52,7 +52,7 @@ PREFIX_KINDS = (
 )
 
 
-def prefix_sum_tpu(x: jnp.ndarray, *, interpret: bool = True) -> jnp.ndarray:
+def prefix_sum_tpu(x: jnp.ndarray, *, interpret: bool) -> jnp.ndarray:
     n = x.shape[0]
     if n % TILE != 0:
         raise ValueError(f"prefix_sum_tpu requires N % {TILE} == 0; got {n}")
@@ -61,7 +61,7 @@ def prefix_sum_tpu(x: jnp.ndarray, *, interpret: bool = True) -> jnp.ndarray:
 
 
 def searchsorted_tpu(
-    cdf: jnp.ndarray, u: jnp.ndarray, *, side: str = "left", interpret: bool = True
+    cdf: jnp.ndarray, u: jnp.ndarray, *, side: str = "left", interpret: bool
 ) -> jnp.ndarray:
     n = cdf.shape[0]
     if n % TILE != 0 or u.shape != (n,):
@@ -101,7 +101,7 @@ def prefix_resample_tpu(
     weights: jnp.ndarray,
     kind: str = "systematic",
     *,
-    interpret: bool = True,
+    interpret: bool,
     plane_dtype="float32",
 ) -> jnp.ndarray:
     """Resample via the scan + search kernels; returns int32[N] ancestors."""
@@ -135,7 +135,7 @@ def prefix_resample_tpu_apply(
     particles: jnp.ndarray,
     kind: str = "systematic",
     *,
-    interpret: bool = True,
+    interpret: bool,
     plane_dtype="float32",
 ):
     """Fused resample+gather for the prefix-sum family (DESIGN.md §11): the
@@ -183,7 +183,7 @@ def prefix_resample_tpu_step(
     ess_threshold,
     kind: str = "systematic",
     *,
-    interpret: bool = True,
+    interpret: bool,
     plane_dtype="float32",
 ):
     """Fused SMC step for the prefix-sum family (DESIGN.md §12): normalise →

@@ -62,7 +62,7 @@ def scan_tiles(x2d: jnp.ndarray) -> jnp.ndarray:
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def prefix_sum_pallas(x2d: jnp.ndarray, *, interpret: bool = True) -> jnp.ndarray:
+def prefix_sum_pallas(x2d: jnp.ndarray, *, interpret: bool) -> jnp.ndarray:
     rows, lanes = x2d.shape
     assert lanes == LANES and rows % SUBLANES == 0
     num_tiles = rows // SUBLANES

@@ -82,7 +82,7 @@ def searchsorted_pallas(
     u2d: jnp.ndarray,
     *,
     side: str = "left",
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """``cdf2d``: non-decreasing f32[R, 128] (flat row-major CDF);
     ``u2d``: f32[R, 128] of search values.  Returns int32[R, 128] indices
@@ -125,7 +125,7 @@ def searchsorted_gather_pallas(
     planes: jnp.ndarray,
     *,
     side: str = "left",
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Fused search+gather (DESIGN.md §11): the bisection result indexes the
     resident state plane stack in the SAME grid step — the prefix-sum
@@ -186,7 +186,7 @@ def residual_select_gather_pallas(
     n_det: jnp.ndarray,
     planes: jnp.ndarray,
     *,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Fused residual tail (DESIGN.md §11): deterministic-copy search,
     residual search, slot select and state gather in one kernel.  ``cc2d``:

@@ -49,8 +49,8 @@ def _full_lane_ids(rows: int) -> jnp.ndarray:
 def _make_kernel_step(n_total: int, rows: int, kind: str):
     def _kernel(u0_ref, thr_ref, lw_ref, ubase_ref, planes_ref,
                 k_ref, out_ref, stats_ref):
-        lw_flat = lw_ref[...].astype(jnp.float32).reshape(n_total)
-        m, ess_norm, incr, maxw, deg = step_stats(lw_flat, n_total)
+        m, ess_norm, incr, maxw, deg = step_stats(
+            lw_ref[...].astype(jnp.float32), n_total)
         do = ess_norm < thr_ref[0]
         stats_ref[0] = ess_norm
         stats_ref[1] = jnp.where(do, incr, jnp.float32(0.0))
@@ -108,7 +108,7 @@ def prefix_pallas_step(
     thr: jnp.ndarray,
     *,
     kind: str,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Fused SMC-step pallas_call for one prefix-sum kind.  ``ubase2d``:
     the key-only uniform base draws reshaped (R, 128) (zeros for the

@@ -42,7 +42,7 @@ def rejection_tpu(
     weights: jnp.ndarray,
     *,
     max_iters: int = 1024,
-    interpret: bool = True,
+    interpret: bool,
     plane_dtype="float32",
 ) -> jnp.ndarray:
     n = weights.shape[0]
@@ -58,7 +58,7 @@ def rejection_tpu_batch(
     weights: jnp.ndarray,
     *,
     max_iters: int = 1024,
-    interpret: bool = True,
+    interpret: bool,
     plane_dtype="float32",
 ) -> jnp.ndarray:
     """One ``[B, R, 128]`` launch; row b == ``rejection_tpu(split(key,B)[b],
@@ -79,7 +79,7 @@ def rejection_tpu_apply(
     particles: jnp.ndarray,
     *,
     max_iters: int = 1024,
-    interpret: bool = True,
+    interpret: bool,
     plane_dtype="float32",
 ):
     """Fused resample+gather (DESIGN.md §11): ancestors identical to
@@ -118,7 +118,7 @@ def rejection_tpu_apply_batch(
     particles: jnp.ndarray,
     *,
     max_iters: int = 1024,
-    interpret: bool = True,
+    interpret: bool,
     plane_dtype="float32",
 ):
     """Fused bank launch under the §4 split-key contract; row b ==
@@ -141,7 +141,7 @@ def rejection_tpu_step(
     ess_threshold,
     *,
     max_iters: int = 1024,
-    interpret: bool = True,
+    interpret: bool,
     plane_dtype="float32",
 ):
     """Fused SMC step (DESIGN.md §12): normalise → ESS → conditional
@@ -174,7 +174,7 @@ def rejection_tpu_step_rows(
     ess_threshold,
     *,
     max_iters: int = 1024,
-    interpret: bool = True,
+    interpret: bool,
     plane_dtype="float32",
 ):
     """Fused SMC-step bank over EXPLICIT per-row keys; row b ==
@@ -202,7 +202,7 @@ def rejection_tpu_apply_rows(
     particles: jnp.ndarray,
     *,
     max_iters: int = 1024,
-    interpret: bool = True,
+    interpret: bool,
     plane_dtype="float32",
 ):
     """Fused bank launch over EXPLICIT per-row keys; row b ==

@@ -142,7 +142,7 @@ def _make_kernel_step(max_iters: int):
         @pl.when(t == 0)
         def _prelude():
             m, ess_norm, incr, maxw, deg = step_stats(
-                lw_full_ref[...].astype(jnp.float32).reshape(n_total), n_total
+                lw_full_ref[...].astype(jnp.float32), n_total
             )
             do = ess_norm < thr_ref[0]
             st_ref[0] = m
@@ -190,7 +190,7 @@ def _make_kernel_step_rows(max_iters: int):
         @pl.when(t == 0)
         def _prelude():
             m, ess_norm, incr, maxw, deg = step_stats(
-                lw_full_ref[0].astype(jnp.float32).reshape(n_total), n_total
+                lw_full_ref[0].astype(jnp.float32), n_total
             )
             do = ess_norm < thr_ref[0]
             st_ref[0] = m
@@ -230,7 +230,7 @@ def rejection_pallas_step(
     thr: jnp.ndarray,
     *,
     max_iters: int,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Fused SMC-step pallas_call: normalise → ESS → conditional rejection
     chain → state copy, ONE launch.  ``log_weights2d``: f32[R, 128]
@@ -279,7 +279,7 @@ def rejection_pallas_step_rows(
     thr: jnp.ndarray,
     *,
     max_iters: int,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Fused SMC-step bank launch; row s is bit-identical to
     ``rejection_pallas_step(log_weights3d[s], planes4d[s], seeds[s:s+1],
@@ -329,7 +329,7 @@ def rejection_pallas_fused(
     seed: jnp.ndarray,
     *,
     max_iters: int,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Fused resample+gather (DESIGN.md §11): the rejection chain runs
     entirely inside the kernel body, so the state copy follows it in the
@@ -374,7 +374,7 @@ def rejection_pallas_fused_batch(
     seeds: jnp.ndarray,
     *,
     max_iters: int,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Fused bank launch (leading batch grid dim); row s is bit-identical to
     ``rejection_pallas_fused(weights3d[s], planes4d[s], seeds[s:s+1])``."""
@@ -419,7 +419,7 @@ def rejection_pallas(
     seed: jnp.ndarray,
     *,
     max_iters: int,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """``weights2d``: f32[R, 128] with R % 8 == 0; ``seed``: uint32[1].
     Returns int32[R, 128] ancestors (last proposal kept past the cap)."""
@@ -451,7 +451,7 @@ def rejection_pallas_batch(
     seeds: jnp.ndarray,
     *,
     max_iters: int,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Batched launch over a ``[Bz, R, 128]`` bank (leading batch grid dim);
     row s is bit-identical to ``rejection_pallas(weights3d[s], seeds[s:s+1])``."""

@@ -17,11 +17,13 @@ from __future__ import annotations
 import jax
 import numpy as np
 
+from repro.compat import make_mesh
+
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh(*, model: int = 1):
@@ -29,7 +31,7 @@ def make_local_mesh(*, model: int = 1):
     unless the caller set --xla_force_host_platform_device_count)."""
     n = len(jax.devices())
     assert n % model == 0, (n, model)
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))
 
 
 def dp_degree(mesh) -> int:

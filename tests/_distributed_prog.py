@@ -28,7 +28,7 @@ from repro.core.metrics import mse, offspring_counts  # noqa: E402
 from repro.core.weightgen import gaussian_weights  # noqa: E402
 from repro.core import megopolis as core_megopolis, select_iterations  # noqa: E402
 from repro.kernels.common import key_to_seed  # noqa: E402
-from repro.compat import make_mesh, shard_map  # noqa: E402
+from repro.compat import make_mesh  # noqa: E402
 
 
 def main():
@@ -82,11 +82,12 @@ def main():
     x = jax.random.normal(jax.random.PRNGKey(7), (n, 3))
     anc = res(k_call, w)
     gathered = jax.jit(
-        shard_map(
+        jax.shard_map(
             lambda xl, al: gather_ancestors(xl, al, axis_name="data"),
             mesh=mesh,
             in_specs=(P("data"), P("data")),
             out_specs=P("data"),
+            check_vma=False,
         )
     )(x, anc)
     np.testing.assert_allclose(np.asarray(gathered), np.asarray(jnp.take(x, anc, axis=0)), rtol=0)
@@ -94,11 +95,12 @@ def main():
 
     # ---- island exchange: preserves multiset of particles
     mixed = jax.jit(
-        shard_map(
+        jax.shard_map(
             lambda xl: island_exchange(xl, axis_name="data", fraction=0.25),
             mesh=mesh,
             in_specs=(P("data"),),
             out_specs=P("data"),
+            check_vma=False,
         )
     )(x)
     np.testing.assert_allclose(
@@ -108,11 +110,12 @@ def main():
 
     # ---- ESS psum
     ess = jax.jit(
-        shard_map(
+        jax.shard_map(
             lambda wl: effective_sample_size(wl, axis_name="data"),
             mesh=mesh,
             in_specs=(P("data"),),
             out_specs=P(),
+            check_vma=False,
         )
     )(w)
     ess_ref = float(jnp.sum(w) ** 2 / jnp.sum(w**2))

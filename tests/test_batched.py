@@ -128,13 +128,13 @@ def test_megopolis_kernel_batch_rows_match_single_bank_kernel(base_key):
 def test_megopolis_tpu_batch_public_api(base_key):
     n, bsz = 2 * TILE, 3
     w = jax.random.uniform(jax.random.fold_in(base_key, 27), (bsz, n)) + 1e-3
-    a = megopolis_tpu_batch(jax.random.fold_in(base_key, 28), w, 16)
+    a = megopolis_tpu_batch(jax.random.fold_in(base_key, 28), w, 16, interpret=True)
     assert a.shape == (bsz, n) and a.dtype == jnp.int32
     assert bool(jnp.all((a >= 0) & (a < n)))
     with pytest.raises(ValueError, match="VMEM tile"):
-        megopolis_tpu_batch(base_key, w[:, : n - 3], 16)
+        megopolis_tpu_batch(base_key, w[:, : n - 3], 16, interpret=True)
     with pytest.raises(ValueError, match=r"\[B, N\]"):
-        megopolis_tpu_batch(base_key, w[0], 16)
+        megopolis_tpu_batch(base_key, w[0], 16, interpret=True)
 
 
 # ---------------------------------------------------------- filter bank

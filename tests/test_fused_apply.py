@@ -292,13 +292,38 @@ def test_compressed_ancestors_bit_identical_to_f32(name, backend, w_single,
 
 
 # ------------------------------------------------------- 4. residency cap
+def _over_state_cap():
+    """A state just past the resident-plane-stack budget."""
+    return MAX_VMEM_STATE // N // STATE_PLANE_TILE * STATE_PLANE_TILE + STATE_PLANE_TILE
+
+
 def test_apply_state_residency_cap(base_key):
-    d = MAX_VMEM_STATE // N // STATE_PLANE_TILE * STATE_PLANE_TILE + STATE_PLANE_TILE
-    p = jnp.zeros((N, d), jnp.float32)
+    """The families that keep the whole plane stack VMEM-resident
+    (Metropolis, rejection) refuse a state past the budget."""
+    p = jnp.zeros((N, _over_state_cap()), jnp.float32)
     w = jnp.ones((N,), jnp.float32)
-    r = _build("megopolis", "pallas_interpret")
+    r = _build("metropolis", "pallas_interpret")
     with pytest.raises(ValueError, match="VMEM"):
         r.apply(base_key, w, p)
+
+
+@pytest.mark.parametrize("entry", ["apply", "apply_rows"])
+def test_megopolis_apply_has_no_state_cap(entry, base_key):
+    """Megopolis carries the ancestor's state by value through the sweep
+    (one aligned tile per load), so no residency cap binds it: a state past
+    the resident budget still copies bit-exactly."""
+    d = _over_state_cap()
+    w = jax.random.uniform(jax.random.PRNGKey(5), (N,)) + 1e-3
+    p = jax.random.normal(jax.random.PRNGKey(6), (N, d))
+    r = _build("megopolis", "pallas_interpret")
+    if entry == "apply":
+        got_p, got_a = r.apply(base_key, w, p)
+        _assert_equal(got_a, r(base_key, w))
+        _assert_equal(got_p, jnp.take(p, got_a, axis=0))
+    else:
+        keys = jax.random.split(base_key, 2)
+        got_p, got_a = r.apply_rows(keys, jnp.stack([w, w[::-1]]), jnp.stack([p, -p]))
+        _assert_equal(got_p[1], jnp.take(-p, got_a[1], axis=0))
 
 
 def test_f16_residency_edge_admits_wider_state(base_key):
@@ -311,8 +336,8 @@ def test_f16_residency_edge_admits_wider_state(base_key):
     w = jnp.ones((n,), jnp.float32)
     p = jnp.zeros((n, d), jnp.float32)
     with pytest.raises(ValueError, match="VMEM"):
-        _build("megopolis", "pallas_interpret").apply(base_key, w, p)
-    r16 = _build("megopolis", "pallas_interpret", "float16")
+        _build("metropolis", "pallas_interpret").apply(base_key, w, p)
+    r16 = _build("metropolis", "pallas_interpret", "float16")
     got_p, got_a = r16.apply(base_key, w, p)
     assert got_p.shape == (n, d)
     _assert_equal(got_p, jnp.take(r16.quantise(p), got_a, axis=0))

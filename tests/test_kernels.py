@@ -66,11 +66,11 @@ def test_megopolis_kernel_matches_ref(n_tiles, num_iters, dtype, base_key):
 def test_megopolis_tpu_public_api(base_key):
     n = 4 * TILE
     w = jax.random.uniform(base_key, (n,)) + 1e-3
-    a = megopolis_tpu(base_key, w, 16)
+    a = megopolis_tpu(base_key, w, 16, interpret=True)
     assert a.shape == (n,) and a.dtype == jnp.int32
     assert bool(jnp.all((a >= 0) & (a < n)))
     with pytest.raises(ValueError):
-        megopolis_tpu(base_key, w[: n - 3], 16)
+        megopolis_tpu(base_key, w[: n - 3], 16, interpret=True)
 
 
 def test_megopolis_kernel_quality_parity(base_key):
@@ -83,7 +83,7 @@ def test_megopolis_kernel_quality_parity(base_key):
     o_kern, o_core = [], []
     for t in range(k_runs):
         kk = jax.random.fold_in(base_key, 500 + t)
-        o_kern.append(np.asarray(offspring_counts(megopolis_tpu(kk, w, num_iters), n)))
+        o_kern.append(np.asarray(offspring_counts(megopolis_tpu(kk, w, num_iters, interpret=True), n)))
         o_core.append(np.asarray(offspring_counts(core_megopolis(kk, w, num_iters), n)))
     m_kern = float(mse(jnp.asarray(np.stack(o_kern)), w)) / n
     m_core = float(mse(jnp.asarray(np.stack(o_core)), w)) / n
@@ -107,7 +107,7 @@ def test_metropolis_tpu_vmem_cap(base_key):
 
     w = jnp.ones((MAX_VMEM_PARTICLES + TILE,))
     with pytest.raises(ValueError, match="VMEM"):
-        metropolis_tpu(base_key, w, 4)
+        metropolis_tpu(base_key, w, 4, interpret=True)
 
 
 @pytest.mark.parametrize("bsz", [1, 3])
@@ -115,9 +115,9 @@ def test_metropolis_batch_kernel_rows_match_single(bsz, base_key):
     """Row b of the [B, R, 128] launch == single kernel with split key b."""
     n = 2 * TILE
     w = jax.random.uniform(jax.random.fold_in(base_key, 21), (bsz, n)) + 1e-3
-    got = metropolis_tpu_batch(base_key, w, 6)
+    got = metropolis_tpu_batch(base_key, w, 6, interpret=True)
     keys = split_batch_keys(base_key, bsz)
-    want = jnp.stack([metropolis_tpu(keys[b], w[b], 6) for b in range(bsz)])
+    want = jnp.stack([metropolis_tpu(keys[b], w[b], 6, interpret=True) for b in range(bsz)])
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
@@ -176,7 +176,7 @@ def test_c1_proposals_stay_in_partition(base_key):
 def test_rejection_kernel_matches_ref(n_tiles, max_iters, base_key):
     n = n_tiles * TILE
     w = jax.random.uniform(jax.random.fold_in(base_key, 61), (n,)) + 1e-3
-    got = rejection_tpu(base_key, w, max_iters=max_iters)
+    got = rejection_tpu(base_key, w, max_iters=max_iters, interpret=True)
     want = rejection_ref(w, key_to_seed(base_key).reshape(1), max_iters=max_iters)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -184,9 +184,9 @@ def test_rejection_kernel_matches_ref(n_tiles, max_iters, base_key):
 def test_rejection_batch_kernel_rows_match_single(base_key):
     n = 2 * TILE
     w = jax.random.uniform(jax.random.fold_in(base_key, 62), (3, n)) + 1e-3
-    got = rejection_tpu_batch(base_key, w, max_iters=16)
+    got = rejection_tpu_batch(base_key, w, max_iters=16, interpret=True)
     keys = split_batch_keys(base_key, 3)
-    want = jnp.stack([rejection_tpu(keys[b], w[b], max_iters=16) for b in range(3)])
+    want = jnp.stack([rejection_tpu(keys[b], w[b], max_iters=16, interpret=True) for b in range(3)])
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
@@ -198,7 +198,7 @@ def test_rejection_kernel_unbiased_in_expectation(base_key):
     runs = 24
     offs = []
     for t in range(runs):
-        a = rejection_tpu(jax.random.fold_in(base_key, 600 + t), w, max_iters=64)
+        a = rejection_tpu(jax.random.fold_in(base_key, 600 + t), w, max_iters=64, interpret=True)
         offs.append(np.asarray(offspring_counts(a, n)))
     mean_off = np.stack(offs).mean(axis=0)
     want = n * np.asarray(w / jnp.sum(w))
@@ -213,7 +213,7 @@ def test_rejection_kernel_unbiased_in_expectation(base_key):
 def test_prefix_sum_matches_ref(n_tiles, dtype, base_key):
     n = n_tiles * TILE
     x = jax.random.uniform(base_key, (n,), jnp.float32).astype(dtype)
-    got = prefix_sum_tpu(x)
+    got = prefix_sum_tpu(x, interpret=True)
     want = prefix_sum_ref(x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6)
 
@@ -223,7 +223,7 @@ def test_prefix_sum_tiled_ref_bit_exact(base_key):
     (the plain-cumsum oracle is only close)."""
     n = 5 * TILE
     x = jax.random.uniform(base_key, (n,), jnp.float32)
-    got = np.asarray(prefix_sum_tpu(x))
+    got = np.asarray(prefix_sum_tpu(x, interpret=True))
     want = np.asarray(prefix_sum_tiled_ref(x))
     np.testing.assert_array_equal(got, want)
 
@@ -233,7 +233,7 @@ def test_searchsorted_kernel_matches_jnp(side, base_key):
     n = 2 * TILE
     c = jnp.sort(jax.random.uniform(jax.random.fold_in(base_key, 71), (n,))) * 100.0
     u = jax.random.uniform(jax.random.fold_in(base_key, 72), (n,)) * 110.0 - 5.0
-    got = searchsorted_tpu(c, u, side=side)
+    got = searchsorted_tpu(c, u, side=side, interpret=True)
     want = jnp.minimum(jnp.searchsorted(c, u, side=side), n - 1).astype(jnp.int32)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -244,7 +244,7 @@ def test_searchsorted_kernel_matches_jnp(side, base_key):
 def test_prefix_resample_kernel_matches_ref(kind, base_key):
     n = 3 * TILE
     w = jax.random.uniform(jax.random.fold_in(base_key, 73), (n,)) + 1e-3
-    got = prefix_resample_tpu(base_key, w, kind)
+    got = prefix_resample_tpu(base_key, w, kind, interpret=True)
     want = prefix_resample_ref(base_key, w, kind=kind)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -254,8 +254,8 @@ def test_prefix_resample_improved_systematic_equals_systematic(base_key):
     two kinds share the search kernel by construction — pin it."""
     n = 2 * TILE
     w = jax.random.uniform(jax.random.fold_in(base_key, 74), (n,)) + 1e-3
-    a = prefix_resample_tpu(base_key, w, "systematic")
-    b = prefix_resample_tpu(base_key, w, "improved_systematic")
+    a = prefix_resample_tpu(base_key, w, "systematic", interpret=True)
+    b = prefix_resample_tpu(base_key, w, "improved_systematic", interpret=True)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
@@ -264,7 +264,7 @@ def test_prefix_sum_f32_instability_story(base_key):
     similar weights drift from the f64 truth as N grows."""
     n = 64 * TILE
     x = jax.random.uniform(base_key, (n,), jnp.float32) + 0.5
-    f32 = np.asarray(prefix_sum_tpu(x))[-1]
+    f32 = np.asarray(prefix_sum_tpu(x, interpret=True))[-1]
     f64 = np.cumsum(np.asarray(x, np.float64))[-1]
     rel = abs(f32 - f64) / f64
     assert rel > 0  # measurable drift exists

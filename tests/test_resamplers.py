@@ -8,7 +8,7 @@ import pytest
 
 from repro.core import get_resampler, list_resamplers, select_iterations
 from repro.core.metrics import offspring_counts
-from repro.core.resamplers.megopolis import megopolis_indices
+from repro.core.resamplers.megopolis import megopolis, megopolis_indices
 
 ALL = list_resamplers()
 N = 512
@@ -144,3 +144,35 @@ def test_rejection_unbiased_mean(base_key):
     emp = counts / counts.sum()
     tgt = np.asarray(w / w.sum())
     assert np.abs(emp - tgt).max() < 0.02
+
+
+def _megopolis_by_gather(key, weights, num_iters, segment):
+    """Alg. 5 as first written: both weights gathered by index each
+    iteration — the reference the rotating, value-carrying form must equal."""
+    n = weights.shape[0]
+    key_off, key_u = jax.random.split(key)
+    offsets = jax.random.randint(key_off, (num_iters,), 0, n)
+    i = jnp.arange(n, dtype=jnp.int32)
+
+    def body(b, k):
+        j = megopolis_indices(i, offsets[b], segment, n).astype(jnp.int32)
+        u = jax.random.uniform(jax.random.fold_in(key_u, b), (n,), weights.dtype)
+        return jnp.where(u * weights[k] <= weights[j], j, k)
+
+    return jax.lax.fori_loop(0, num_iters, body, i)
+
+
+@pytest.mark.parametrize(
+    "n,segment",
+    [(512, 32), (2048, 1024), (512, 1), (500, 32), (1000, 1024)],
+    ids=["rotate-32", "rotate-1024", "rotate-1", "gather-32", "gather-1024"],
+)
+def test_megopolis_matches_gather_form(n, segment, base_key):
+    """Where N is whole segments the comparison weights come from two rolls
+    (else a gather) and w[k] rides a carry; the ancestors are bit-identical
+    to the gather-by-index form either way."""
+    w = _weights(jax.random.fold_in(base_key, 40), n) ** 4
+    key = jax.random.fold_in(base_key, 41)
+    got = jax.jit(lambda k, w: megopolis(k, w, B, segment=segment))(key, w)
+    want = jax.jit(lambda k, w: _megopolis_by_gather(k, w, B, segment))(key, w)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

@@ -219,11 +219,11 @@ def test_pallas_interpret_backend_matches_kernel_wrappers(base_key):
     w = jax.random.uniform(jax.random.fold_in(base_key, 67), (n,)) + 1e-3
     r = MegopolisSpec(num_iters=4, segment=1024, backend="pallas_interpret").build()
     np.testing.assert_array_equal(
-        np.asarray(r(base_key, w)), np.asarray(megopolis_tpu(base_key, w, 4))
+        np.asarray(r(base_key, w)), np.asarray(megopolis_tpu(base_key, w, 4, interpret=True))
     )
     wb = jax.random.uniform(jax.random.fold_in(base_key, 68), (2, n)) + 1e-3
     np.testing.assert_array_equal(
-        np.asarray(r.batch(base_key, wb)), np.asarray(megopolis_tpu_batch(base_key, wb, 4))
+        np.asarray(r.batch(base_key, wb)), np.asarray(megopolis_tpu_batch(base_key, wb, 4, interpret=True))
     )
 
 

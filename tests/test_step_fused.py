@@ -411,12 +411,17 @@ def test_step_rows_rejects_short_key_array(backend, lw_bank, p_bank, base_key):
 
 
 def test_step_state_residency_cap(base_key):
+    """Families with a resident plane stack refuse a state past the budget;
+    Megopolis carries state by value and steps it bit-exactly."""
     d = MAX_VMEM_STATE // N // STATE_PLANE_TILE * STATE_PLANE_TILE + STATE_PLANE_TILE
-    p = jnp.zeros((N, d), jnp.float32)
-    lw = jnp.zeros((N,), jnp.float32)
-    r = _build("megopolis", "pallas_interpret")
+    p = jax.random.normal(jax.random.PRNGKey(3), (N, d))
+    lw = jax.random.normal(jax.random.PRNGKey(4), (N,)) * 2.0
     with pytest.raises(ValueError, match="VMEM"):
-        r.step(base_key, lw, p, 0.5)
+        _build("metropolis", "pallas_interpret").step(base_key, lw, p, 0.5)
+    r = _build("megopolis", "pallas_interpret")
+    p_out, anc, stats = r.step(base_key, lw, p, 0.5)
+    assert float(stats.resampled) == 1.0
+    _assert_equal(p_out, jnp.take(p, anc, axis=0))
 
 
 # ----------------------------------------------------------- 6. consumers
