@@ -290,8 +290,8 @@ class Resampler:
 
     def _span(self, entry: str):
         """The dispatch trace span (DESIGN.md §15):
-        ``family/backend/entry/plane_dtype``.  Identity unless tracing is
-        enabled, so the structural jaxpr gates never see it."""
+        ``family/backend/entry/plane_dtype``.  A named scope: it leaves
+        the jaxpr unchanged, so the structural jaxpr gates never see it."""
         return dispatch_span(
             self.name, getattr(self.spec, "backend", "reference"), entry,
             self.plane_dtype,

@@ -19,6 +19,12 @@ Memory-access contract (DESIGN.md §2):
     ``w_k`` in the CUDA original; the fused kernels carry the ancestor's
     state the same way (DESIGN.md §11).
 
+Each ``pallas_call`` carries a stable ``name=``, after the ``Resampler``
+entry that launches it: ``megopolis_pallas`` (``single``), ``_batch``,
+``_apply``, ``_apply_rows``, ``_step`` and ``_step_rows``.  The name is the
+kernel's, independent of the Python wrapper's, so a profile's reduction can
+find the kernel after a refactor (DESIGN.md §15).
+
 Validated in ``interpret=True`` mode bit-exactly against ``ref.py``, and
 compiled by Mosaic for the TPU (``tests/test_tpu_compile.py``,
 ``chip_smoke.py``).
@@ -336,6 +342,7 @@ def megopolis_pallas(
     return pl.pallas_call(
         _kernel,
         grid_spec=grid_spec,
+        name="megopolis_pallas",
         out_shape=jax.ShapeDtypeStruct((rows, lanes), jnp.int32),
         interpret=interpret,
     )(offsets, seed, weights2d, weights2d)
@@ -386,6 +393,7 @@ def megopolis_pallas_batch(
     return pl.pallas_call(
         _kernel_batch,
         grid_spec=grid_spec,
+        name="megopolis_pallas_batch",
         out_shape=jax.ShapeDtypeStruct((bsz, rows, lanes), jnp.int32),
         interpret=interpret,
     )(offsets, seeds, weights3d, weights3d)
@@ -441,6 +449,7 @@ def megopolis_pallas_fused(
     return pl.pallas_call(
         _kernel_fused,
         grid_spec=grid_spec,
+        name="megopolis_pallas_apply",
         out_shape=[
             jax.ShapeDtypeStruct((rows, lanes), jnp.int32),
             jax.ShapeDtypeStruct((d_pad, rows, lanes), planes.dtype),
@@ -502,6 +511,7 @@ def megopolis_pallas_fused_rows(
     return pl.pallas_call(
         _kernel_fused_rows,
         grid_spec=grid_spec,
+        name="megopolis_pallas_apply_rows",
         out_shape=[
             jax.ShapeDtypeStruct((bsz, rows, lanes), jnp.int32),
             jax.ShapeDtypeStruct((bsz, d_pad, rows, lanes), planes4d.dtype),
@@ -567,6 +577,7 @@ def megopolis_pallas_step(
     return pl.pallas_call(
         _kernel_step,
         grid_spec=grid_spec,
+        name="megopolis_pallas_step",
         out_shape=[
             jax.ShapeDtypeStruct((rows, lanes), jnp.int32),
             jax.ShapeDtypeStruct((d_pad, rows, lanes), planes.dtype),
@@ -629,6 +640,7 @@ def megopolis_pallas_step_rows(
     return pl.pallas_call(
         _kernel_step_rows,
         grid_spec=grid_spec,
+        name="megopolis_pallas_step_rows",
         out_shape=[
             jax.ShapeDtypeStruct((bsz, rows, lanes), jnp.int32),
             jax.ShapeDtypeStruct((bsz, d_pad, rows, lanes), planes4d.dtype),

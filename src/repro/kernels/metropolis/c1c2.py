@@ -204,7 +204,7 @@ def _make_kernel_step(p_at):
 
 
 def _c1c2_step_call(kernel, log_weights2d, planes, partitions, seed, thr, *,
-                    num_iters, part_index, interpret):
+                    name, num_iters, part_index, interpret):
     """Shared fused-step pallas_call builder for the C1/C2 pair: the fused
     apply layout plus a resident whole-log-weight input for the prelude and
     an SMEM stats output."""
@@ -236,6 +236,7 @@ def _c1c2_step_call(kernel, log_weights2d, planes, partitions, seed, thr, *,
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
+        name=name,
         out_shape=[
             jax.ShapeDtypeStruct((rows, lanes), jnp.int32),
             jax.ShapeDtypeStruct((d_pad, rows, lanes), planes.dtype),
@@ -262,6 +263,7 @@ def metropolis_c1_pallas_step(
     return _c1c2_step_call(
         _make_kernel_step(lambda p, t, b: p[t]),
         log_weights2d, planes, partitions, seed, thr,
+        name="metropolis_c1_pallas_step",
         num_iters=num_iters,
         part_index=lambda t, b, p, se, r: (p[t], 0),
         interpret=interpret,
@@ -284,6 +286,7 @@ def metropolis_c2_pallas_step(
     return _c1c2_step_call(
         _make_kernel_step(lambda p, t, b: p[t * num_iters + b]),
         log_weights2d, planes, partitions, seed, thr,
+        name="metropolis_c2_pallas_step",
         num_iters=num_iters,
         part_index=lambda t, b, p, se, r: (p[t * num_iters + b], 0),
         interpret=interpret,
@@ -291,7 +294,7 @@ def metropolis_c2_pallas_step(
 
 
 def _c1c2_fused_call(kernel, weights2d, planes, partitions, seed, *,
-                     num_iters, part_index, interpret):
+                     name, num_iters, part_index, interpret):
     """Shared fused pallas_call builder for the C1/C2 pair — identical
     except for the partition BlockSpec index map."""
     rows, lanes = weights2d.shape
@@ -317,6 +320,7 @@ def _c1c2_fused_call(kernel, weights2d, planes, partitions, seed, *,
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
+        name=name,
         out_shape=[
             jax.ShapeDtypeStruct((rows, lanes), jnp.int32),
             jax.ShapeDtypeStruct((d_pad, rows, lanes), planes.dtype),
@@ -339,6 +343,7 @@ def metropolis_c1_pallas_fused(
     ``(int32[R, 128], [d_pad, R, 128])``."""
     return _c1c2_fused_call(
         _kernel_c1_fused, weights2d, planes, partitions, seed,
+        name="metropolis_c1_pallas_apply",
         num_iters=num_iters,
         part_index=lambda t, b, p, seed: (p[t], 0),
         interpret=interpret,
@@ -359,6 +364,7 @@ def metropolis_c2_pallas_fused(
     ``(int32[R, 128], [d_pad, R, 128])``."""
     return _c1c2_fused_call(
         _make_kernel_c2_fused(num_iters), weights2d, planes, partitions, seed,
+        name="metropolis_c2_pallas_apply",
         num_iters=num_iters,
         part_index=lambda t, b, p, seed: (p[t * num_iters + b], 0),
         interpret=interpret,
@@ -396,6 +402,7 @@ def metropolis_c1_pallas(
     return pl.pallas_call(
         _kernel_c1,
         grid_spec=grid_spec,
+        name="metropolis_c1_pallas",
         out_shape=jax.ShapeDtypeStruct((rows, lanes), jnp.int32),
         interpret=interpret,
     )(partitions, seed, weights2d, weights2d)
@@ -433,6 +440,7 @@ def metropolis_c2_pallas(
     return pl.pallas_call(
         _make_kernel_c2(num_iters),
         grid_spec=grid_spec,
+        name="metropolis_c2_pallas",
         out_shape=jax.ShapeDtypeStruct((rows, lanes), jnp.int32),
         interpret=interpret,
     )(partitions, seed, weights2d, weights2d)

@@ -240,6 +240,7 @@ def metropolis_pallas(
     return pl.pallas_call(
         _kernel,
         grid_spec=grid_spec,
+        name="metropolis_pallas",
         out_shape=jax.ShapeDtypeStruct((rows, lanes), jnp.int32),
         interpret=interpret,
     )(seed, weights2d, weights2d)
@@ -280,6 +281,7 @@ def metropolis_pallas_batch(
     return pl.pallas_call(
         _kernel_batch,
         grid_spec=grid_spec,
+        name="metropolis_pallas_batch",
         out_shape=jax.ShapeDtypeStruct((bsz, rows, lanes), jnp.int32),
         interpret=interpret,
     )(seeds, weights3d, weights3d)
@@ -320,6 +322,7 @@ def metropolis_pallas_fused(
     return pl.pallas_call(
         _kernel_fused,
         grid_spec=grid_spec,
+        name="metropolis_pallas_apply",
         out_shape=[
             jax.ShapeDtypeStruct((rows, lanes), jnp.int32),
             jax.ShapeDtypeStruct((d_pad, rows, lanes), planes.dtype),
@@ -367,6 +370,7 @@ def metropolis_pallas_fused_batch(
     return pl.pallas_call(
         _kernel_fused_batch,
         grid_spec=grid_spec,
+        name="metropolis_pallas_apply_rows",
         out_shape=[
             jax.ShapeDtypeStruct((bsz, rows, lanes), jnp.int32),
             jax.ShapeDtypeStruct((bsz, d_pad, rows, lanes), planes4d.dtype),
@@ -418,6 +422,7 @@ def metropolis_pallas_step(
     return pl.pallas_call(
         _kernel_step,
         grid_spec=grid_spec,
+        name="metropolis_pallas_step",
         out_shape=[
             jax.ShapeDtypeStruct((rows, lanes), jnp.int32),
             jax.ShapeDtypeStruct((d_pad, rows, lanes), planes.dtype),
@@ -472,6 +477,7 @@ def metropolis_pallas_step_rows(
     return pl.pallas_call(
         _kernel_step_rows,
         grid_spec=grid_spec,
+        name="metropolis_pallas_step_rows",
         out_shape=[
             jax.ShapeDtypeStruct((bsz, rows, lanes), jnp.int32),
             jax.ShapeDtypeStruct((bsz, d_pad, rows, lanes), planes4d.dtype),

@@ -69,6 +69,7 @@ def prefix_sum_pallas(x2d: jnp.ndarray, *, interpret: bool) -> jnp.ndarray:
     return pl.pallas_call(
         _kernel,
         grid=(num_tiles,),
+        name="prefix_sum_pallas_scan",
         in_specs=[pl.BlockSpec((SUBLANES, LANES), lambda t: (t, 0))],
         out_specs=pl.BlockSpec((SUBLANES, LANES), lambda t: (t, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, lanes), jnp.float32),

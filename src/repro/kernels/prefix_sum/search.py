@@ -97,6 +97,7 @@ def searchsorted_pallas(
     return pl.pallas_call(
         _make_kernel(n_total, side),
         grid=(num_tiles,),
+        name="prefix_sum_pallas_search",
         in_specs=[
             # whole CDF resident; fetched once (block index constant in t)
             pl.BlockSpec((rows, LANES), lambda t: (0, 0)),
@@ -143,6 +144,7 @@ def searchsorted_gather_pallas(
     return pl.pallas_call(
         _make_kernel_fused(n_total, side),
         grid=(num_tiles,),
+        name="prefix_sum_pallas_apply",
         in_specs=[
             pl.BlockSpec((rows, LANES), lambda t: (0, 0)),
             pl.BlockSpec((SUBLANES, LANES), lambda t: (t, 0)),
@@ -220,6 +222,7 @@ def residual_select_gather_pallas(
     return pl.pallas_call(
         _make_kernel_residual_fused(n_total),
         grid_spec=grid_spec,
+        name="prefix_sum_pallas_apply_residual",
         out_shape=[
             jax.ShapeDtypeStruct((rows, lanes), jnp.int32),
             jax.ShapeDtypeStruct((d_pad, rows, lanes), planes.dtype),

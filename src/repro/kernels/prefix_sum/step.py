@@ -139,6 +139,7 @@ def prefix_pallas_step(
     return pl.pallas_call(
         _make_kernel_step(n_total, rows, kind),
         grid_spec=grid_spec,
+        name="prefix_sum_pallas_step",
         out_shape=[
             jax.ShapeDtypeStruct((rows, lanes), jnp.int32),
             jax.ShapeDtypeStruct((d_pad, rows, lanes), planes.dtype),

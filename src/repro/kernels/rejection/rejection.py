@@ -262,6 +262,7 @@ def rejection_pallas_step(
     return pl.pallas_call(
         _make_kernel_step(max_iters),
         grid_spec=grid_spec,
+        name="rejection_pallas_step",
         out_shape=[
             jax.ShapeDtypeStruct((rows, lanes), jnp.int32),
             jax.ShapeDtypeStruct((d_pad, rows, lanes), planes.dtype),
@@ -313,6 +314,7 @@ def rejection_pallas_step_rows(
     return pl.pallas_call(
         _make_kernel_step_rows(max_iters),
         grid_spec=grid_spec,
+        name="rejection_pallas_step_rows",
         out_shape=[
             jax.ShapeDtypeStruct((bsz, rows, lanes), jnp.int32),
             jax.ShapeDtypeStruct((bsz, d_pad, rows, lanes), planes4d.dtype),
@@ -359,6 +361,7 @@ def rejection_pallas_fused(
     return pl.pallas_call(
         _make_kernel_fused(max_iters),
         grid_spec=grid_spec,
+        name="rejection_pallas_apply",
         out_shape=[
             jax.ShapeDtypeStruct((rows, lanes), jnp.int32),
             jax.ShapeDtypeStruct((d_pad, rows, lanes), planes.dtype),
@@ -405,6 +408,7 @@ def rejection_pallas_fused_batch(
     return pl.pallas_call(
         _make_kernel_fused_batch(max_iters),
         grid_spec=grid_spec,
+        name="rejection_pallas_apply_rows",
         out_shape=[
             jax.ShapeDtypeStruct((bsz, rows, lanes), jnp.int32),
             jax.ShapeDtypeStruct((bsz, d_pad, rows, lanes), planes4d.dtype),
@@ -440,6 +444,7 @@ def rejection_pallas(
     return pl.pallas_call(
         _make_kernel(max_iters),
         grid_spec=grid_spec,
+        name="rejection_pallas",
         out_shape=jax.ShapeDtypeStruct((rows, lanes), jnp.int32),
         interpret=interpret,
     )(seed, w_max, weights2d, weights2d)
@@ -472,6 +477,7 @@ def rejection_pallas_batch(
     return pl.pallas_call(
         _make_kernel_batch(max_iters),
         grid_spec=grid_spec,
+        name="rejection_pallas_batch",
         out_shape=jax.ShapeDtypeStruct((bsz, rows, lanes), jnp.int32),
         interpret=interpret,
     )(seeds, w_max, weights3d, weights3d)

@@ -8,8 +8,9 @@ Four small layers, strictly ordered by distance from the kernels:
 - ``obs.telemetry``  — ``Telemetry``, the scan-carried trajectory record the
   consumers (`run_filter`, `run_smc_sampler`, `smc_decode`) return when
   asked; structurally absent from the jaxpr when off.
-- ``obs.trace``      — nested profiler spans naming every dispatch
-  ``family/backend/entry/plane_dtype``; a no-op unless enabled.
+- ``obs.trace``      — named scopes, always on and metadata only: the
+  filter's stages (``pf/...``) and every dispatch
+  ``family/backend/entry/plane_dtype``.
 - ``obs.sink``       — JSONL event emitter for the benchmark harness.
 
 The invariant tying them together: telemetry NEVER changes what a program
@@ -20,15 +21,13 @@ estimates with it on or off (analyzer pass 6, ``analysis/telemetry.py``).
 from repro.obs.sink import JsonlSink
 from repro.obs.stats import StepStats, stats_from_vector
 from repro.obs.telemetry import Telemetry
-from repro.obs.trace import dispatch_span, enable_tracing, span, tracing_enabled
+from repro.obs.trace import dispatch_span, span
 
 __all__ = [
     "JsonlSink",
     "StepStats",
     "Telemetry",
     "dispatch_span",
-    "enable_tracing",
     "span",
     "stats_from_vector",
-    "tracing_enabled",
 ]

@@ -1,48 +1,30 @@
-"""Nested profiler spans over dispatch boundaries (DESIGN.md §15).
+"""Named scopes over the program's work (DESIGN.md §15).
 
-``span(name)`` wraps a region in BOTH ``jax.profiler.TraceAnnotation`` (so
-host-side work lands on the profiler timeline under ``name``) and
-``jax.named_scope`` (so the traced ops carry ``name`` into the jaxpr/HLO
-metadata and XLA traces attribute device time to it).  ``Resampler``
-dispatch opens one per public entry, named::
+``span(name)`` is ``jax.named_scope(name)``: the ops traced inside it carry
+``name`` in their name stack, which lowering writes into each HLO op's
+``metadata={op_name=...}`` and the profiler reports per device op as its
+``tf_op``.  A scope is metadata only: the jaxpr, and the compiled program
+once metadata is stripped, are those of the unscoped code, and the
+persistent compilation cache's key leaves metadata out.  So spans are
+always on, with no switch.
 
-    family/backend/entry/plane_dtype     e.g. megopolis/pallas/step/bfloat16
+Two kinds are opened:
 
-Disabled (the default) it is an identity context manager — no profiler
-import, no named_scope, zero trace-time cost — so the §12/§13 structural
-gates (identical-jaxpr comparisons, launch-count audits) see the exact
-same program whether or not a profiler ever attaches.  Enable with
-``REPRO_TRACE=1`` in the environment or ``enable_tracing()`` in code.
+* the filter's stages, ``pf/predict``, ``pf/update``, ``pf/resample`` and
+  ``pf/estimate`` (``pf/filter.py``);
+* one per ``Resampler`` public entry, ``dispatch_span``::
+
+      family/backend/entry/plane_dtype     e.g. megopolis/pallas/step/bfloat16
 """
 
 from __future__ import annotations
 
-import contextlib
-import os
-
-_enabled = os.environ.get("REPRO_TRACE", "0") not in ("", "0", "false", "no")
+import jax
 
 
-def enable_tracing(on: bool = True) -> None:
-    """Turn span emission on/off process-wide (overrides ``REPRO_TRACE``)."""
-    global _enabled
-    _enabled = bool(on)
-
-
-def tracing_enabled() -> bool:
-    return _enabled
-
-
-@contextlib.contextmanager
 def span(name: str):
-    """Profiler + named_scope span around a region; identity when disabled."""
-    if not _enabled:
-        yield
-        return
-    import jax
-
-    with jax.profiler.TraceAnnotation(name), jax.named_scope(name):
-        yield
+    """A named scope around a region; costs nothing in the compiled program."""
+    return jax.named_scope(name)
 
 
 def dispatch_span(family: str, backend: str, entry: str, plane_dtype="float32"):

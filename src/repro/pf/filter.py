@@ -4,13 +4,17 @@ The modified SIR filter (Alg. 6) drops weight normalisation — the
 Metropolis-family resamplers only use weight *ratios* — and estimates the
 state as the post-resampling particle mean (uniform weights).
 
-Three execution modes:
+Two execution modes:
   * ``run_filter``: fully jitted ``lax.scan`` over time steps (production).
   * ``run_filter_bank``: S independent filters — a SCENARIO axis of
     observation streams, model parameters and keys — under ONE jitted scan
     whose resampling step is a single batched launch (DESIGN.md §4).
-  * ``run_filter_timed``: per-stage host timing (predict+update / resample /
-    estimate) for the paper's Resample-Ratio metric (eq. 25).
+
+Every step names its stages with always-on scopes (``obs/trace.py``,
+DESIGN.md §15): ``pf/predict``, ``pf/update``, ``pf/resample`` and
+``pf/estimate``; the key splits and the scan stay outside them.  A profile
+attributes each device op to its stage by the first ``pf/`` name in its
+name stack.
 
 Model callables take ``(key, x, t)``; scenario-parameterised models take a
 trailing ``theta`` pytree (``(key, x, t, theta)``), enabling per-scenario
@@ -20,7 +24,6 @@ dynamics in the bank (see ``repro.pf.models.ungm_family``).
 from __future__ import annotations
 
 import dataclasses
-import time
 import warnings
 from typing import Callable, Optional, Union
 
@@ -40,6 +43,7 @@ from repro.core.resamplers.batched import split_batch_keys
 from repro.core.spec import ResamplerSpec, coerce_spec
 from repro.obs.stats import StepStats
 from repro.obs.telemetry import Telemetry
+from repro.obs.trace import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -125,12 +129,17 @@ class ParticleFilter:
         pre-telemetry program unchanged."""
         k_pred, k_res = jax.random.split(key)
         # Stage 1: predict + update
-        x = _call(self.model.transition, k_pred, particles, t, theta=theta)
-        w = _call(self.model.likelihood, z, x, t, theta=theta)
+        with span("pf/predict"):
+            x = _call(self.model.transition, k_pred, particles, t, theta=theta)
+        with span("pf/update"):
+            w = _call(self.model.likelihood, z, x, t, theta=theta)
         # Stage 2: fused resample + ancestor gather
-        x_bar, ancestors = self._built.apply(k_res, w, x)
+        with span("pf/resample"):
+            x_bar, ancestors = self._built.apply(k_res, w, x)
         # Stage 3: estimate (uniform post-resampling weights)
-        return x_bar, jnp.mean(x_bar), w, ancestors
+        with span("pf/estimate"):
+            est = jnp.mean(x_bar)
+        return x_bar, est, w, ancestors
 
     def step_conditional(self, key, particles, log_w, z, t, theta=None):
         """One conditional-SIR step (classic ESS-triggered SIR, DESIGN.md
@@ -145,19 +154,23 @@ class ParticleFilter:
         resample, so the Alg. 6 plain mean would be biased)."""
         k_pred, k_res = jax.random.split(key)
         # Stage 1: predict + update (log-weight accumulation)
-        x = _call(self.model.transition, k_pred, particles, t, theta=theta)
-        w = _call(self.model.likelihood, z, x, t, theta=theta)
-        log_w = log_w + log_weights_from_linear(w)
+        with span("pf/predict"):
+            x = _call(self.model.transition, k_pred, particles, t, theta=theta)
+        with span("pf/update"):
+            w = _call(self.model.likelihood, z, x, t, theta=theta)
+            log_w = log_w + log_weights_from_linear(w)
         # Stage 3 first: the estimate consumes the pre-resample weights
-        wn = normalise_log_weights(log_w)
-        est = jnp.sum(wn * x) / jnp.sum(wn)
+        with span("pf/estimate"):
+            wn = normalise_log_weights(log_w)
+            est = jnp.sum(wn * x) / jnp.sum(wn)
         # Stage 2: fused normalise → ESS → conditional resample → gather
-        x_bar, _, stats = self._built.step(
-            k_res, log_w, x, self.ess_threshold
-        )
-        log_w = jnp.where(
-            stats.ess_norm < self.ess_threshold, jnp.zeros_like(log_w), log_w
-        )
+        with span("pf/resample"):
+            x_bar, _, stats = self._built.step(
+                k_res, log_w, x, self.ess_threshold
+            )
+            log_w = jnp.where(
+                stats.ess_norm < self.ess_threshold, jnp.zeros_like(log_w), log_w
+            )
         return x_bar, log_w, est, stats
 
 
@@ -339,35 +352,42 @@ def run_filter_bank(key, pf: ParticleFilter, observations: jnp.ndarray, thetas=N
         pr = jax.vmap(jax.random.split)(step_keys)
         k_pred, k_res = pr[:, 0], pr[:, 1]
         # Stage 1 (batched): predict + update
-        x = jax.vmap(
-            lambda k, xr, th: _call(pf.model.transition, k, xr, t, theta=th),
-            in_axes=(0, 0, theta_axes),
-        )(k_pred, xs, thetas)
-        w = jax.vmap(
-            lambda z, xr, th: _call(pf.model.likelihood, z, xr, t, theta=th),
-            in_axes=(0, 0, theta_axes),
-        )(zs, x, thetas)
+        with span("pf/predict"):
+            x = jax.vmap(
+                lambda k, xr, th: _call(pf.model.transition, k, xr, t, theta=th),
+                in_axes=(0, 0, theta_axes),
+            )(k_pred, xs, thetas)
+        with span("pf/update"):
+            w = jax.vmap(
+                lambda z, xr, th: _call(pf.model.likelihood, z, xr, t, theta=th),
+                in_axes=(0, 0, theta_axes),
+            )(zs, x, thetas)
+            if conditional:
+                log_w = log_w + log_weights_from_linear(w)
         if conditional:
             # Conditional SIR: accumulate log-weights, estimate from the
             # pre-resample posterior, then ONE fused step_rows launch —
             # stage arithmetic mirrors step_conditional row for row.
-            log_w = log_w + log_weights_from_linear(w)
-            wn = normalise_log_weights(log_w, axis=-1)
-            est = jnp.sum(wn * x, axis=1) / jnp.sum(wn, axis=1)
-            x_bar, _, stats = resampler.step_rows(
-                k_res, log_w, x, pf.ess_threshold
-            )
-            log_w = jnp.where(
-                (stats.ess_norm < pf.ess_threshold)[:, None], 0.0, log_w
-            )
+            with span("pf/estimate"):
+                wn = normalise_log_weights(log_w, axis=-1)
+                est = jnp.sum(wn * x, axis=1) / jnp.sum(wn, axis=1)
+            with span("pf/resample"):
+                x_bar, _, stats = resampler.step_rows(
+                    k_res, log_w, x, pf.ess_threshold
+                )
+                log_w = jnp.where(
+                    (stats.ess_norm < pf.ess_threshold)[:, None], 0.0, log_w
+                )
             out = (est, stats) if telemetry else est
             return (x_bar, log_w, ks_next), out
         # Stage 2: ONE batched FUSED resample+gather launch for the whole
         # bank (Resampler.apply_rows, DESIGN.md §11) — on the batch-grid
         # kernel families this is a single fused launch per step
-        x_bar, ancestors = resampler.apply_rows(k_res, w, x)
+        with span("pf/resample"):
+            x_bar, ancestors = resampler.apply_rows(k_res, w, x)
         # Stage 3 (batched): estimate
-        est = jnp.mean(x_bar, axis=1)
+        with span("pf/estimate"):
+            est = jnp.mean(x_bar, axis=1)
         out = (est, _alg6_step_stats(w, ancestors)) if telemetry else est
         return (x_bar, log_w, ks_next), out
 
@@ -380,50 +400,3 @@ def run_filter_bank(key, pf: ParticleFilter, observations: jnp.ndarray, thetas=N
     # Scan stacks time first ([T, S] per field); transpose to the [S, T]
     # estimate layout so row s is the single filter's trajectory.
     return ests.T, Telemetry(steps=jax.tree.map(jnp.transpose, steps))
-
-
-def run_filter_timed(key, pf: ParticleFilter, observations, warmup: int = 2):
-    """Per-stage wall timing for the Resample-Ratio metric (paper eq. 25).
-
-    Stages are jitted separately and block_until_ready'd so the split is
-    honest; the first ``warmup`` steps are excluded (compile time).
-    """
-    model = pf.model
-
-    @jax.jit
-    def stage1(k, x, z, t):
-        x = model.transition(k, x, t)
-        return x, model.likelihood(z, x, t)
-
-    @jax.jit
-    def stage2(k, x, w):
-        x_bar, _ = pf._built.apply(k, w, x)
-        return x_bar
-
-    @jax.jit
-    def stage3(x):
-        return jnp.mean(x)
-
-    k0, key = jax.random.split(key)
-    particles = model.init(k0, pf.num_particles)
-    times = {"predict_update": 0.0, "resample": 0.0, "estimate": 0.0}
-    ests = []
-    for i, z in enumerate(observations):
-        key, k1, k2 = jax.random.split(key, 3)
-        t = jnp.float32(i + 1)
-        t0 = time.perf_counter()
-        x, w = stage1(k1, particles, z, t)
-        jax.block_until_ready(w)
-        t1 = time.perf_counter()
-        particles = stage2(k2, x, w)
-        jax.block_until_ready(particles)
-        t2 = time.perf_counter()
-        est = stage3(particles)
-        jax.block_until_ready(est)
-        t3 = time.perf_counter()
-        if i >= warmup:
-            times["predict_update"] += t1 - t0
-            times["resample"] += t2 - t1
-            times["estimate"] += t3 - t2
-        ests.append(float(est))
-    return jnp.asarray(ests), times

@@ -1,4 +1,4 @@
-"""Filtering metrics: RMSE (paper eq. 24) and resample ratio (eq. 25)."""
+"""Filtering metrics: RMSE (paper eq. 24)."""
 
 from __future__ import annotations
 
@@ -14,9 +14,3 @@ def rmse(estimates: np.ndarray, truth: np.ndarray) -> float:
     # sqrt over the K Monte-Carlo axis first, then average over time.
     per_t = np.sqrt(np.mean((est - tru[None, :]) ** 2, axis=0))
     return float(np.mean(per_t))
-
-
-def resample_ratio(times: dict) -> float:
-    """tau_s2 / (tau_s1 + tau_s2 + tau_s3), eq. (25)."""
-    total = times["predict_update"] + times["resample"] + times["estimate"]
-    return times["resample"] / max(total, 1e-12)

@@ -17,12 +17,16 @@ Contract under test:
   5. **with_ess shim** — the deprecated diagnostic still returns the old
      ``(estimates, ess_norm)`` pair bit-identically, warns, and refuses to
      combine with ``telemetry=True``.
-  6. **spans + sink** — disabled spans are identity at trace time (the
-     structural gates depend on it); the JSONL sink round-trips events in
-     order and stringifies rather than drops odd values.
+  6. **spans + sink** — spans are always-on named scopes and metadata
+     only: the jaxpr and the compiled program (metadata aside) are those of
+     unscoped code (the structural gates depend on it); the filter's four
+     ``pf/*`` stages reach the HLO ``op_name`` metadata; every Megopolis
+     ``pallas_call`` carries its stable name; the JSONL sink round-trips
+     events in order and stringifies rather than drops odd values.
 """
 
 import json
+import re
 
 import jax
 import jax.numpy as jnp
@@ -41,10 +45,8 @@ from repro.obs import (
     StepStats,
     Telemetry,
     dispatch_span,
-    enable_tracing,
     span,
     stats_from_vector,
-    tracing_enabled,
 )
 from repro.pf import ParticleFilter, run_filter, run_filter_bank, ungm
 
@@ -276,34 +278,124 @@ def test_with_ess_shim_warns_and_matches_telemetry(base_key):
 
 # --------------------------------------------------------- 6. spans + sink
 def test_span_disabled_is_trace_identity():
-    """Disabled spans must leave the jaxpr untouched — the §12/§13
-    identical-program gates compare traces across dispatches that open
-    spans against compositions that don't."""
-    assert not tracing_enabled()  # default-off (REPRO_TRACE unset in CI)
+    """Spans are always-on named scopes that leave the jaxpr untouched — the
+    §12/§13 identical-program gates compare traces across dispatches that
+    open spans against compositions that don't."""
 
     def plain(x):
         return jnp.sum(x * 2.0)
 
     def spanned(x):
-        with dispatch_span("megopolis", "reference", "step"):
+        with dispatch_span("megopolis", "reference", "step"), span("pf/estimate"):
             return jnp.sum(x * 2.0)
 
     x = jnp.arange(8, dtype=jnp.float32)
     assert str(jax.make_jaxpr(plain)(x)) == str(jax.make_jaxpr(spanned)(x))
     np.testing.assert_array_equal(np.asarray(plain(x)),
                                   np.asarray(spanned(x)))
+    # ...yet the scope is on: it names the traced equations
+    eqn = jax.make_jaxpr(spanned)(x).jaxpr.eqns[0]
+    assert str(eqn.source_info.name_stack) == "megopolis/reference/step/float32/pf/estimate"
 
 
-def test_span_enabled_still_computes():
-    enable_tracing(True)
-    try:
-        assert tracing_enabled()
-        with span("obs-test/enabled"):
-            out = float(jnp.sum(jnp.ones(4)))
-        assert out == 4.0
-    finally:
-        enable_tracing(False)
-    assert not tracing_enabled()
+STAGES = ("pf/predict", "pf/update", "pf/resample", "pf/estimate")
+
+
+def _filter_entry(entry):
+    """(jittable fn, args) of one filter entry at a small size."""
+    model = ungm()
+    key = jax.random.PRNGKey(0)
+    spec = spec_for_backend("megopolis", "xla", num_iters=4)
+    x, z, t = jnp.zeros((N,), jnp.float32), jnp.float32(1.0), jnp.float32(1.0)
+    if entry == "step":
+        pf = ParticleFilter(model, N, resampler=spec)
+        return pf.step, (key, x, z, t)
+    if entry == "step_conditional":
+        pf = ParticleFilter(model, N, resampler=spec, ess_threshold=0.5)
+        return pf.step_conditional, (key, x, jnp.zeros_like(x), z, t)
+    pf = ParticleFilter(model, N, resampler=spec, ess_threshold=0.5)
+    return (lambda k, zs: run_filter_bank(k, pf, zs)), (key, jnp.ones((2, 3), jnp.float32))
+
+
+@pytest.mark.parametrize("entry", ("step", "step_conditional", "run_filter_bank"))
+def test_filter_stages_reach_hlo_op_names(entry):
+    fn, args = _filter_entry(entry)
+    hlo = jax.jit(fn).lower(*args).as_text(dialect="hlo", debug_info=True)
+    op_names = set(re.findall(r'op_name="([^"]*)"', hlo))
+    for stage in STAGES:
+        assert any(re.match(f"(.*/)?{stage}/", n) for n in op_names), (stage, sorted(op_names))
+    # the resampler's own dispatch span nests inside its stage
+    assert any(re.match("(.*/)?pf/resample/megopolis/xla/", n) for n in op_names)
+
+
+def _strip_metadata(hlo_text):
+    """The program alone: no ``metadata={...}``, no source-location tables."""
+    lines = [ln for ln in hlo_text.splitlines()
+             if not re.match(r"(FileNames|FunctionNames|FileLocations|StackFrames)$|\d+ ", ln)]
+    return re.sub(r",? metadata=\{[^}]*\}", "", "\n".join(lines))
+
+
+def test_scopes_leave_the_compiled_program_unchanged():
+    """A scoped Alg. 6 step and the same statements unscoped compile to
+    the same HLO once ``metadata={...}`` is stripped."""
+    model = ungm()
+    pf = ParticleFilter(model, N, resampler=spec_for_backend("megopolis", "xla", num_iters=4))
+
+    def step(key, particles, z, t):  # ParticleFilter.step without its scopes
+        k_pred, k_res = jax.random.split(key)
+        x = model.transition(k_pred, particles, t)
+        w = model.likelihood(z, x, t)
+        x_bar, ancestors = pf._built.apply(k_res, w, x)
+        return x_bar, jnp.mean(x_bar), w, ancestors
+
+    args = (jax.random.PRNGKey(0), jnp.zeros((N,), jnp.float32), jnp.float32(1.0),
+            jnp.float32(1.0))
+    scoped = jax.jit(pf.step).lower(*args).compile().as_text()
+    unscoped = jax.jit(step).lower(*args).compile().as_text()
+    assert "pf/predict" in scoped and "pf/predict" not in unscoped
+    assert _strip_metadata(scoped) == _strip_metadata(unscoped)
+
+
+MEGOPOLIS_KERNELS = {
+    "single": "megopolis_pallas",
+    "batch": "megopolis_pallas_batch",
+    "apply": "megopolis_pallas_apply",
+    "apply_rows": "megopolis_pallas_apply_rows",
+    "step": "megopolis_pallas_step",
+    "step_rows": "megopolis_pallas_step_rows",
+}
+
+
+def _pallas_names(jaxpr):
+    """``params["name"]`` of every ``pallas_call`` equation, nested jaxprs
+    included."""
+    names = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(eqn.params["name"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names += _pallas_names(sub)
+    return names
+
+
+@pytest.mark.parametrize("entry", sorted(MEGOPOLIS_KERNELS))
+def test_megopolis_pallas_calls_carry_stable_names(entry):
+    r = spec_for_backend("megopolis", "pallas_interpret", num_iters=4).build()
+    key = jax.random.PRNGKey(0)
+    w, x = jnp.ones((N,), jnp.float32), jnp.zeros((N,), jnp.float32)
+    keys = jax.random.split(key, 2)
+    call = {
+        "single": lambda: (r, (key, w)),
+        "batch": lambda: (r.batch, (key, jnp.stack([w, w]))),
+        "apply": lambda: (r.apply, (key, w, x)),
+        "apply_rows": lambda: (r.apply_rows, (keys, jnp.stack([w, w]), jnp.stack([x, x]))),
+        "step": lambda: (r.step, (key, jnp.zeros_like(w), x, 0.5)),
+        "step_rows": lambda: (r.step_rows, (keys, jnp.zeros((2, N)), jnp.stack([x, x]), 0.5)),
+    }[entry]
+    fn, args = call()
+    names = _pallas_names(jax.make_jaxpr(fn)(*args).jaxpr)
+    assert names == [MEGOPOLIS_KERNELS[entry]]
+    assert all(n.startswith("megopolis_pallas") for n in names)  # the benchmark's kernel_pattern
 
 
 def test_jsonl_sink_round_trips_in_order(tmp_path):

@@ -6,8 +6,8 @@ import numpy as np
 import pytest
 
 from repro.pf import ParticleFilter, run_filter, ungm
-from repro.pf.filter import run_filter_timed, simulate
-from repro.pf.metrics import resample_ratio, rmse
+from repro.pf.filter import simulate
+from repro.pf.metrics import rmse
 
 T = 50
 N_PARTICLES = 4096
@@ -45,15 +45,6 @@ def test_megopolis_rmse_close_to_unbiased(trajectory):
     r_m = rmse(np.stack(runs_m), xs)
     r_s = rmse(np.stack(runs_s), xs)
     assert r_m < 1.25 * r_s, (r_m, r_s)
-
-
-def test_resample_ratio_metric(trajectory):
-    xs, zs = trajectory
-    pf = ParticleFilter(ungm(), 2048, resampler="megopolis", num_iters=16)
-    ests, times = run_filter_timed(jax.random.PRNGKey(3), pf, jnp.asarray(zs)[:10])
-    ratio = resample_ratio(times)
-    assert 0.0 < ratio < 1.0
-    assert np.isfinite(np.asarray(ests)).all()
 
 
 def test_filter_resampler_is_pluggable(trajectory):
