@@ -68,6 +68,7 @@ def phase_a(jax, jnp, np):
     from repro.core.spec import MegopolisSpec
     from repro.core.weightgen import gaussian_weights
     from repro.kernels.common import key_to_seed
+    from repro.kernels.megopolis.megopolis import tiles_per_step
     from repro.kernels.megopolis.ref import megopolis_ref
 
     r = MegopolisSpec(num_iters=SEG_ITERS, segment=1024, backend="pallas").build()
@@ -88,6 +89,8 @@ def phase_a(jax, jnp, np):
 
     # -- fused apply: ancestors == call, particles' == particles[ancestors]
     n = N_FILTER
+    print(f"INFO tiles per grid step of the fused apply and step at N=2^20, state_dim 1: "
+          f"G={tiles_per_step(n // 1024, 1, 4)}", flush=True)
     kw, kc, kp = jax.random.split(jax.random.fold_in(key, 100), 3)
     w = gaussian_weights(kw, n, 2.0)
     anc_call = r(kc, w)

@@ -156,9 +156,10 @@ def vmem_budget_bytes() -> int:
     weight planes (the analyzer's estimate for the prefix-family fused step
     at N*pad_state_dim == MAX_VMEM_STATE is 19.0 MiB).  At the defaults this
     is 2 * (4 MiB + 8 MiB) + 2 MiB = 26 MiB: more than the 16 MiB a v5e
-    kernel may use without raising ``vmem_limit_bytes``, which no kernel
-    here does.  It prices the resident-stack families, none of which Mosaic
-    compiles yet (DESIGN.md §2); the Megopolis kernels stay far below it."""
+    kernel may use without raising ``vmem_limit_bytes``, which of the
+    compiled kernels only the Megopolis fused step does (DESIGN.md §2).  It
+    prices the resident-stack families, none of which Mosaic compiles yet;
+    the Megopolis kernels stay below it."""
     return 2 * 4 * (MAX_VMEM_PARTICLES + MAX_VMEM_STATE) + VMEM_FOOTPRINT_SLACK_BYTES
 
 

@@ -10,6 +10,13 @@ Memory-access contract (DESIGN.md §2):
     offset table: ``(t + o[b] // SEG) mod num_tiles`` — so every load the
     kernel ever issues is a whole, aligned, contiguous tile (the paper's
     Fig. 4b "wrapped sequential" pattern, 0 wasted words);
+  * the fused ``apply`` and ``step`` kernels sweep G tiles per grid step,
+    grid = (num_tiles / G, B): block t's comparison window, tiles
+    ``t*G + g + o[b] // SEG``, is fetched as two aligned G-tile blocks
+    (``_window_index``) and tile g reads its comparison tile from one of
+    them (``_window_tile``).  G is a function of the shapes alone
+    (``tiles_per_step``: a power of two dividing num_tiles whose blocks fit
+    the kernel's VMEM); an odd num_tiles gives G = 1, the one-tile grid;
   * the intra-segment wrap ``(i + o[b]) mod SEG`` is a register-level flat
     roll of the tile — no extra memory traffic;
   * per-(particle, iteration) uniforms come from a stateless counter hash
@@ -36,6 +43,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -97,6 +105,112 @@ def _state_index_rows(index):
         s, t, _ = index(*args)
         return s, 0, t, 0
     return state
+
+
+#: Most tiles one grid step of the fused ``apply`` and ``step`` kernels
+#: sweeps (``tiles_per_step``).
+MAX_TILES_PER_STEP = 128
+
+#: VMEM the G-tile blocks of one fused launch may take (``tiles_per_step``):
+#: inside the 16 MiB a v5e kernel may use without raising
+#: ``vmem_limit_bytes``, with room for the compiler's own scratch.
+BLOCK_VMEM_BYTES = 12 << 20
+
+#: The scoped VMEM a v5e kernel gets unless it raises ``vmem_limit_bytes``.
+DEFAULT_VMEM_LIMIT_BYTES = 16 << 20
+
+#: Tiles per iteration of the in-kernel tile loop: a partial unroll, so the
+#: scheduler may interleave the tiles' independent chains, while the code
+#: the compiler sees stays one loop body whatever G is.  At N = 2^20 on a
+#: TPU v5e, 8 takes 2%, 6% and 10-13% less kernel time than 4, 2 and 1
+#: (PERF.md §6).
+_TILE_UNROLL = 8
+
+
+def block_vmem_bytes(g_tiles, d_pad, itemsize):
+    """VMEM of one fused launch's G-tile blocks: the own and the two
+    comparison blocks of the weights and of the ``d_pad`` state planes,
+    the ancestor and state outputs, each double-buffered, plus the
+    ``w[k]`` and state carries (f32 words).  ``itemsize`` is the widest
+    plane word.  The v5e compiler plans the blocked ``apply`` within
+    0.05 MiB of this."""
+    tile_bytes = TILE * itemsize
+    blocks = 2 * (3 * tile_bytes + 4 * d_pad * tile_bytes + TILE * 4)
+    carries = TILE * 4 + d_pad * TILE * 4
+    return g_tiles * (blocks + carries)
+
+
+def tiles_per_step(num_tiles, d_pad, itemsize):
+    """G, the tiles one grid step of the fused kernels sweeps: the largest
+    power of two up to ``MAX_TILES_PER_STEP`` that divides ``num_tiles``
+    and whose blocks fit ``BLOCK_VMEM_BYTES``.  An odd ``num_tiles`` gives
+    1, one tile per grid step."""
+    g = MAX_TILES_PER_STEP
+    while g > 1 and (num_tiles % g
+                     or block_vmem_bytes(g, d_pad, itemsize) > BLOCK_VMEM_BYTES):
+        g //= 2
+    return g
+
+
+def step_vmem_limit_bytes(log_weights2d, block_bytes):
+    """Scoped VMEM for one fused step launch: its G-tile blocks beside what
+    it holds for the whole launch, the resident log-weight block and the
+    prelude's two whole-array f32 temporaries (11.9 MiB at N = 2^20 in
+    f32 as the v5e compiler plans it), plus 1 MiB for the compiler's own
+    scratch; never below the default."""
+    resident = log_weights2d.size * (log_weights2d.dtype.itemsize + 2 * 4)
+    return max(DEFAULT_VMEM_LIMIT_BYTES, resident + block_bytes + (1 << 20))
+
+
+def _window_index(num_blocks, g_tiles, second):
+    """Block index map of the first (``second`` = 0) or second comparison
+    block of a G-tile block grid: tiles ``t*G + g`` compare against tiles
+    ``(t*G + g + o // SEG) mod num_tiles``, a window that starts
+    ``(o // SEG) mod G`` tiles into aligned block
+    ``q = (t + o // SEG // G) mod num_blocks`` and ends in block
+    ``(q + 1) mod num_blocks``.  At G = 1 the window is one whole block and
+    the second block is never read: its map is constant, so it is fetched
+    once per launch."""
+    if g_tiles == 1 and second:
+        return lambda t, b, offs, *_: (0, 0)
+
+    def index(t, b, offs, *_):
+        return (t + offs[b] // SEG // g_tiles + second) % num_blocks, 0
+
+    return index
+
+
+def _tile_rows(g):
+    """Rows of tile ``g`` of a G-tile block: an aligned 8-row slice."""
+    return pl.ds(pl.multiple_of(g * SUBLANES, SUBLANES), SUBLANES)
+
+
+def _window_tile(lo_ref, hi_ref, s, g_tiles):
+    """Tile ``s`` (0 <= s < 2G) of the comparison window the two aligned
+    blocks ``lo_ref`` and ``hi_ref`` hold: tile s of the first block while
+    s < G, else tile s - G of the second.  Both loads are aligned tiles at a
+    dynamic row offset; a select keeps one.  Weight blocks are
+    ``(G*8, 128)``, state blocks ``(d_pad, G*8, 128)``."""
+    lead = (slice(None),) * (len(lo_ref.shape) - 2)
+    first = lo_ref[lead + (_tile_rows(jnp.minimum(s, g_tiles - 1)), slice(None))]
+    second = hi_ref[lead + (_tile_rows(jnp.maximum(s - g_tiles, 0)), slice(None))]
+    return jnp.where(s < g_tiles, first, second)
+
+
+def _for_each_tile(g_tiles, o, tile):
+    """Call ``tile(g, s)`` for g = 0 .. G-1 in order: tile g of the block
+    compares against tile ``s = (o // SEG) mod G + g`` of its window.  One
+    loop iteration runs ``_TILE_UNROLL`` tiles (all G when fewer)."""
+    r = (o // SEG) % g_tiles
+    unroll = min(_TILE_UNROLL, g_tiles)  # both powers of two
+
+    def body(i, carry):
+        for j in range(unroll):
+            g = i * unroll + j
+            tile(g, r + g)
+        return carry
+
+    lax.fori_loop(0, g_tiles // unroll, body, 0)
 
 
 def _carry_state(b, o, accept, x_own, x_cmp, xk_prev):
@@ -173,32 +287,40 @@ def _kernel_batch(offsets_ref, seeds_ref, w_own_ref, w_cmp_ref, k_ref, wk_ref):
     wk_ref[...] = wk_new
 
 
-def _kernel_fused(offsets_ref, seed_ref, w_own_ref, w_cmp_ref, x_own_ref,
-                  x_cmp_ref, k_ref, out_ref, wk_ref, xk_ref):
-    """Fused resample+gather grid step (t, b): the Alg. 5 sweep with the
-    ancestor's state carried by value beside ``w[k]`` (DESIGN.md §11); the
-    LAST iteration writes the carried state tile to the output.  The
-    ancestor index never round-trips through HBM between selection and
-    copy."""
+def _kernel_fused(offsets_ref, seed_ref, w_own_ref, w_lo_ref, w_hi_ref,
+                  x_own_ref, x_lo_ref, x_hi_ref, k_ref, out_ref, wk_ref, xk_ref):
+    """Fused resample+gather grid step (t, b): the Alg. 5 sweep of the G
+    tiles of block t at iteration b, with each ancestor's state carried by
+    value beside ``w[k]`` (DESIGN.md §11); the LAST iteration writes the
+    carried state tiles to the output.  The ancestor index never
+    round-trips through HBM between selection and copy."""
     t = pl.program_id(0)
     b = pl.program_id(1)
     o = offsets_ref[b]
-    n_total = pl.num_programs(0) * SEG
-    k_new, wk_new, accept = _sweep(
-        t, b, o, seed_ref[0],
-        w_own_ref[...].astype(jnp.float32), w_cmp_ref[...].astype(jnp.float32),
-        k_ref[...], wk_ref[...], n_total,
-    )
-    k_ref[...] = k_new
-    wk_ref[...] = wk_new
+    g_tiles = k_ref.shape[0] // SUBLANES
+    n_total = pl.num_programs(0) * (g_tiles * SEG)
     cd = xk_ref.dtype
-    xk_new = _carry_state(b, o, accept, x_own_ref[...].astype(cd),
-                          x_cmp_ref[...].astype(cd), xk_ref[...])
-    xk_ref[...] = xk_new
 
-    @pl.when(b == pl.num_programs(1) - 1)
-    def _copy_state():
-        out_ref[...] = xk_new.astype(out_ref.dtype)
+    def tile(g, s):
+        own = _tile_rows(g)
+        k_new, wk_new, accept = _sweep(
+            t * g_tiles + g, b, o, seed_ref[0],
+            w_own_ref[own, :].astype(jnp.float32),
+            _window_tile(w_lo_ref, w_hi_ref, s, g_tiles).astype(jnp.float32),
+            k_ref[own, :], wk_ref[own, :], n_total,
+        )
+        k_ref[own, :] = k_new
+        wk_ref[own, :] = wk_new
+        xk_new = _carry_state(b, o, accept, x_own_ref[:, own, :].astype(cd),
+                              _window_tile(x_lo_ref, x_hi_ref, s, g_tiles).astype(cd),
+                              xk_ref[:, own, :])
+        xk_ref[:, own, :] = xk_new
+
+        @pl.when(b == pl.num_programs(1) - 1)
+        def _copy_state():
+            out_ref[:, own, :] = xk_new.astype(out_ref.dtype)
+
+    _for_each_tile(g_tiles, o, tile)
 
 
 def _kernel_fused_rows(offsets_ref, seeds_ref, w_own_ref, w_cmp_ref,
@@ -229,22 +351,24 @@ def _kernel_fused_rows(offsets_ref, seeds_ref, w_own_ref, w_cmp_ref,
         out_ref[0] = xk_new.astype(out_ref.dtype)
 
 
-def _kernel_step(offsets_ref, seed_ref, thr_ref, lw_own_ref, lw_cmp_ref,
-                 lw_full_ref, x_own_ref, x_cmp_ref, k_ref, out_ref, stats_ref,
-                 wk_ref, xk_ref, st_ref):
+def _kernel_step(offsets_ref, seed_ref, thr_ref, lw_own_ref, lw_lo_ref,
+                 lw_hi_ref, lw_full_ref, x_own_ref, x_lo_ref, x_hi_ref, k_ref,
+                 out_ref, stats_ref, wk_ref, xk_ref, st_ref):
     """Fused STEP grid step (t, b): the whole SMC resample decision on-chip.
 
     At (0, 0) a prelude reduces the resident log-weight array to the step
     statistics (normalisation shift m, normalised ESS, log-evidence
     increment) and latches the resample decision ``ess_norm < threshold``
-    into SMEM scratch.  Every sweep then runs on ``exp(lw - m)`` — the SAME
-    normalised weights the composed path hands to ``apply`` — and the last
-    iteration's epilogue either commits the selected ancestors and carried
-    state or the identity permutation and the tile's own state."""
+    into SMEM scratch.  Every sweep of the block's G tiles then runs on
+    ``exp(lw - m)`` — the SAME normalised weights the composed path hands
+    to ``apply`` — and the last iteration's epilogue either commits the
+    selected ancestors and carried state or the identity permutation and
+    the tile's own state."""
     t = pl.program_id(0)
     b = pl.program_id(1)
     o = offsets_ref[b]
-    n_total = pl.num_programs(0) * SEG
+    g_tiles = k_ref.shape[0] // SUBLANES
+    n_total = pl.num_programs(0) * (g_tiles * SEG)
 
     @pl.when((t == 0) & (b == 0))
     def _():
@@ -252,23 +376,32 @@ def _kernel_step(offsets_ref, seed_ref, thr_ref, lw_own_ref, lw_cmp_ref,
                  st_ref, stats_ref, ())
 
     do = st_ref[1] > 0.5
-    w_own, w_cmp = _step_weights(lw_own_ref[...], lw_cmp_ref[...], st_ref[0],
-                                 st_ref[2] > 0.5, n_total, lw_own_ref.dtype)
-    k_new, wk_new, accept = _sweep(
-        t, b, o, seed_ref[0], w_own, w_cmp, k_ref[...], wk_ref[...], n_total,
-    )
-    k_ref[...] = k_new
-    wk_ref[...] = wk_new
     cd = xk_ref.dtype
-    x_own = x_own_ref[...].astype(cd)
-    xk_new = _carry_state(b, o, accept, x_own, x_cmp_ref[...].astype(cd),
-                          xk_ref[...])
-    xk_ref[...] = xk_new
 
-    @pl.when(b == pl.num_programs(1) - 1)
-    def _commit():
-        k_ref[...] = step_select(do, k_new, t)
-        out_ref[...] = jnp.where(do, xk_new, x_own).astype(out_ref.dtype)
+    def tile(g, s):
+        own = _tile_rows(g)
+        t_global = t * g_tiles + g
+        w_own, w_cmp = _step_weights(
+            lw_own_ref[own, :], _window_tile(lw_lo_ref, lw_hi_ref, s, g_tiles),
+            st_ref[0], st_ref[2] > 0.5, n_total, lw_own_ref.dtype)
+        k_new, wk_new, accept = _sweep(
+            t_global, b, o, seed_ref[0], w_own, w_cmp, k_ref[own, :],
+            wk_ref[own, :], n_total,
+        )
+        k_ref[own, :] = k_new
+        wk_ref[own, :] = wk_new
+        x_own = x_own_ref[:, own, :].astype(cd)
+        xk_new = _carry_state(b, o, accept, x_own,
+                              _window_tile(x_lo_ref, x_hi_ref, s, g_tiles).astype(cd),
+                              xk_ref[:, own, :])
+        xk_ref[:, own, :] = xk_new
+
+        @pl.when(b == pl.num_programs(1) - 1)
+        def _commit():
+            k_ref[own, :] = step_select(do, k_new, t_global)
+            out_ref[:, own, :] = jnp.where(do, xk_new, x_own).astype(out_ref.dtype)
+
+    _for_each_tile(g_tiles, o, tile)
 
 
 def _kernel_step_rows(offsets_ref, seeds_ref, thr_ref, lw_own_ref, lw_cmp_ref,
@@ -410,39 +543,44 @@ def megopolis_pallas_fused(
     interpret: bool,
 ):
     """Fused resample+gather pallas_call (DESIGN.md §11).  ``planes``:
-    particle state as a ``[d_pad, R, 128]`` plane stack, streamed tile by
-    tile beside the weights (own and comparison blocks);
-    other arguments as for ``megopolis_pallas``.  Returns ``(ancestors
-    int32[R, 128], state [d_pad, R, 128])`` — the ancestor stream is
-    identical to the unfused kernel's (same sweep arithmetic, same RNG)."""
+    particle state as a ``[d_pad, R, 128]`` plane stack, streamed a block
+    of G tiles at a time beside the weights (own and comparison blocks,
+    G from ``tiles_per_step``); other arguments as for
+    ``megopolis_pallas``.  Returns ``(ancestors int32[R, 128], state
+    [d_pad, R, 128])`` — the ancestor stream is identical to the unfused
+    kernel's (same sweep arithmetic, same RNG)."""
     rows, lanes = weights2d.shape
     assert lanes == LANES and rows % SUBLANES == 0
     d_pad = planes.shape[0]
     assert planes.shape[1:] == (rows, lanes)
     num_tiles = rows // SUBLANES
-
-    def _cmp_index(t, b, offs, seed):
-        return (t + offs[b] // SEG) % num_tiles, 0
+    itemsize = max(weights2d.dtype.itemsize, planes.dtype.itemsize)
+    g_tiles = tiles_per_step(num_tiles, d_pad, itemsize)
+    num_blocks = num_tiles // g_tiles
 
     def _own_index(t, b, offs, seed):
         return t, 0
 
-    state_block = (d_pad, SUBLANES, LANES)
+    block = (g_tiles * SUBLANES, LANES)
+    state_block = (d_pad,) + block
+    lo, hi = (_window_index(num_blocks, g_tiles, second) for second in (0, 1))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(num_tiles, num_iters),
+        grid=(num_blocks, num_iters),
         in_specs=[
-            pl.BlockSpec((SUBLANES, LANES), _own_index),
-            pl.BlockSpec((SUBLANES, LANES), _cmp_index),
+            pl.BlockSpec(block, _own_index),
+            pl.BlockSpec(block, lo),
+            pl.BlockSpec(block, hi),
             pl.BlockSpec(state_block, _state_index(_own_index)),
-            pl.BlockSpec(state_block, _state_index(_cmp_index)),
+            pl.BlockSpec(state_block, _state_index(lo)),
+            pl.BlockSpec(state_block, _state_index(hi)),
         ],
         out_specs=[
-            pl.BlockSpec((SUBLANES, LANES), _own_index),
+            pl.BlockSpec(block, _own_index),
             pl.BlockSpec(state_block, _state_index(_own_index)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((SUBLANES, LANES), jnp.float32),
+            pltpu.VMEM(block, jnp.float32),
             pltpu.VMEM(state_block, _carry_dtype(planes.dtype)),
         ],
     )
@@ -455,7 +593,7 @@ def megopolis_pallas_fused(
             jax.ShapeDtypeStruct((d_pad, rows, lanes), planes.dtype),
         ],
         interpret=interpret,
-    )(offsets, seed, weights2d, weights2d, planes, planes)
+    )(offsets, seed, weights2d, weights2d, weights2d, planes, planes, planes)
 
 
 @functools.partial(jax.jit, static_argnames=("num_iters", "interpret"))
@@ -533,9 +671,10 @@ def megopolis_pallas_step(
 ):
     """Fused SMC-step pallas_call (DESIGN.md §12): normalise → ESS →
     conditional resample → state copy, ONE launch.  ``log_weights2d``:
-    f32[R, 128] UNNORMALISED log-weights (streamed per tile AND kept
-    whole-array resident for the on-chip reduction — the step form
-    inherits the whole-weights VMEM cap); ``thr``: f32[1] ESS/N trigger.
+    f32[R, 128] UNNORMALISED log-weights (streamed a block of G tiles at
+    a time AND kept whole-array resident for the on-chip reduction — the
+    step form inherits the whole-weights VMEM cap); ``thr``: f32[1] ESS/N
+    trigger.
     Returns ``(ancestors int32[R, 128], state [d_pad, R, 128], stats f32[4]
     = (ess_norm, log_evidence_incr, resampled, max_weight) — the in-kernel
     StepStats vector of DESIGN.md §15)``."""
@@ -544,39 +683,46 @@ def megopolis_pallas_step(
     d_pad = planes.shape[0]
     assert planes.shape[1:] == (rows, lanes)
     num_tiles = rows // SUBLANES
+    itemsize = max(log_weights2d.dtype.itemsize, planes.dtype.itemsize)
+    g_tiles = tiles_per_step(num_tiles, d_pad, itemsize)
+    num_blocks = num_tiles // g_tiles
 
     def _own_index(t, b, offs, seed, thr):
         return t, 0
 
-    def _cmp_index(t, b, offs, seed, thr):
-        return (t + offs[b] // SEG) % num_tiles, 0
-
-    state_block = (d_pad, SUBLANES, LANES)
+    block = (g_tiles * SUBLANES, LANES)
+    state_block = (d_pad,) + block
+    lo, hi = (_window_index(num_blocks, g_tiles, second) for second in (0, 1))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,  # offsets + seed + f32 ESS threshold
-        grid=(num_tiles, num_iters),
+        grid=(num_blocks, num_iters),
         in_specs=[
-            pl.BlockSpec((SUBLANES, LANES), _own_index),
-            pl.BlockSpec((SUBLANES, LANES), _cmp_index),
+            pl.BlockSpec(block, _own_index),
+            pl.BlockSpec(block, lo),
+            pl.BlockSpec(block, hi),
             # whole log-weight array resident for the (0,0) stats prelude
             pl.BlockSpec((rows, LANES), lambda t, b, o, s, r: (0, 0)),
             pl.BlockSpec(state_block, _state_index(_own_index)),
-            pl.BlockSpec(state_block, _state_index(_cmp_index)),
+            pl.BlockSpec(state_block, _state_index(lo)),
+            pl.BlockSpec(state_block, _state_index(hi)),
         ],
         out_specs=[
-            pl.BlockSpec((SUBLANES, LANES), _own_index),
+            pl.BlockSpec(block, _own_index),
             pl.BlockSpec(state_block, _state_index(_own_index)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         scratch_shapes=[
-            pltpu.VMEM((SUBLANES, LANES), jnp.float32),
+            pltpu.VMEM(block, jnp.float32),
             pltpu.VMEM(state_block, _carry_dtype(planes.dtype)),
             pltpu.SMEM((3,), jnp.float32),  # (m, do, deg) latch across grid steps
         ],
     )
+    vmem_limit = step_vmem_limit_bytes(
+        log_weights2d, block_vmem_bytes(g_tiles, d_pad, itemsize))
     return pl.pallas_call(
         _kernel_step,
         grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
         name="megopolis_pallas_step",
         out_shape=[
             jax.ShapeDtypeStruct((rows, lanes), jnp.int32),
@@ -584,8 +730,8 @@ def megopolis_pallas_step(
             jax.ShapeDtypeStruct((4,), jnp.float32),
         ],
         interpret=interpret,
-    )(offsets, seed, thr, log_weights2d, log_weights2d, log_weights2d, planes,
-      planes)
+    )(offsets, seed, thr, log_weights2d, log_weights2d, log_weights2d,
+      log_weights2d, planes, planes, planes)
 
 
 @functools.partial(jax.jit, static_argnames=("num_iters", "interpret"))

@@ -397,3 +397,124 @@ def test_filter_step_is_fused_and_matches_reference(base_key):
     anc = pf._built(k_res, w_ref)
     _assert_equal(x_bar, jnp.take(x, anc, axis=0))
     _assert_equal(w, w_ref)
+
+
+# ------------------------------- 6. blocked grid: G tiles per grid step
+#: Tile counts of the blocked-geometry parity cases, with the G that
+#: ``tiles_per_step`` takes for each at these small shapes.
+BLOCKED_G = {1: 1, 3: 1, 12: 4, 16: 16}
+BLOCKED_ITERS = 3
+
+
+def blocked_offsets(table, n, g):
+    """Hand-made offset tables (one offset per iteration) that put every
+    block's comparison window where the blocked grid splits it: ``r0``
+    starts it on a block boundary (``(o // 1024) mod G == 0``), ``rlast``
+    one tile short of the next block (``G - 1``), and ``wrap`` at tile
+    ``num_tiles - 1``, so the last block's window runs past the end of the
+    array into block 0."""
+    num_tiles = n // TILE
+    starts = {
+        "r0": ((0, 5), (g, 1000), (0, 1023)),
+        "rlast": ((g - 1, 77), (2 * g - 1, 1), (g - 1, 0)),
+        "wrap": ((num_tiles - 1, 1023), (num_tiles - 1, 3), (num_tiles - 1, 512)),
+    }[table]
+    return jnp.array([(c * TILE + s) % n for c, s in starts], jnp.int32)
+
+
+def blocked_inputs(num_tiles, plane_dtype, state_dim, key):
+    """Weights, particles and the plane stack the fused kernels take, all
+    on the ``plane_dtype`` grid."""
+    from repro.kernels.common import compress_plane, quantise_plane
+
+    n = num_tiles * TILE
+    kw, kp = jax.random.split(key)
+    w = quantise_plane(jax.random.uniform(kw, (n,)) + 1e-3, plane_dtype)
+    shape = (n,) if state_dim == 1 else (n, state_dim)
+    p = quantise_plane(jax.random.normal(kp, shape), plane_dtype)
+    planes, state_shape = pack_state_planes(p)
+    return w, p, compress_plane(planes, plane_dtype), state_shape
+
+
+@pytest.mark.parametrize("state_dim", (1, 4))
+@pytest.mark.parametrize("plane_dtype", PLANE_DTYPES_TESTED)
+@pytest.mark.parametrize("table", ("r0", "rlast", "wrap"))
+@pytest.mark.parametrize("num_tiles", sorted(BLOCKED_G))
+def test_blocked_apply_matches_ref(num_tiles, table, plane_dtype, state_dim):
+    """The fused apply kernel, called directly at tile counts that give
+    G = 1, 1, 4 and 16, equals the ``megopolis_ref`` oracle and the state
+    gather of its ancestors bit for bit, wherever the window starts."""
+    from repro.kernels.common import compress_plane
+    from repro.kernels.megopolis.megopolis import (
+        megopolis_pallas_fused,
+        tiles_per_step,
+    )
+    from repro.kernels.megopolis.ref import megopolis_ref
+
+    n = num_tiles * TILE
+    w, p, planes, state_shape = blocked_inputs(num_tiles, plane_dtype, state_dim,
+                                               jax.random.PRNGKey(num_tiles))
+    w2 = compress_plane(w.reshape(n // 128, 128), plane_dtype)
+    g = tiles_per_step(num_tiles, planes.shape[0], w2.dtype.itemsize)
+    assert g == BLOCKED_G[num_tiles]
+    offsets = blocked_offsets(table, n, g)
+    seed = jnp.array([2021 + num_tiles], jnp.uint32)
+    k2, out = megopolis_pallas_fused(w2, planes, offsets, seed,
+                                     num_iters=BLOCKED_ITERS, interpret=True)
+    ancestors = megopolis_ref(w, offsets, seed, num_iters=BLOCKED_ITERS)
+    _assert_equal(k2.reshape(n), ancestors)
+    _assert_equal(unpack_state_planes(out.astype(p.dtype), state_shape),
+                  jnp.take(p, ancestors, axis=0))
+
+
+@pytest.mark.parametrize("entry", ("apply", "step"))
+def test_tiles_per_step_at_cell_shapes(entry):
+    """G at the benchmark cells' shape (N = 2^20, one f32 state plane) is
+    the cap for both kernels; an odd tile count keeps one tile per grid
+    step; a wide state (8 planes: ``apply`` at N = 2^22, ``step`` at
+    N = 2^20) takes a smaller G whose blocks fit the budget.  The step
+    raises its scoped VMEM to hold its resident log-weights and prelude
+    beside the blocks."""
+    from repro.kernels.megopolis.megopolis import (
+        BLOCK_VMEM_BYTES,
+        DEFAULT_VMEM_LIMIT_BYTES,
+        MAX_TILES_PER_STEP,
+        block_vmem_bytes,
+        step_vmem_limit_bytes,
+        tiles_per_step,
+    )
+
+    n = 1 << 20
+    assert tiles_per_step(n // TILE, 1, 4) == MAX_TILES_PER_STEP
+    for odd in (1, 3, 1023):
+        assert tiles_per_step(odd, 1, 4) == 1
+    n_wide = 1 << 22 if entry == "apply" else n
+    wide = tiles_per_step(n_wide // TILE, 8, 4)
+    assert 1 < wide < MAX_TILES_PER_STEP
+    assert block_vmem_bytes(wide, 8, 4) <= BLOCK_VMEM_BYTES < block_vmem_bytes(2 * wide, 8, 4)
+    if entry == "step":
+        lw = jax.ShapeDtypeStruct((n // 128, 128), jnp.float32)
+        limit = step_vmem_limit_bytes(lw, block_vmem_bytes(MAX_TILES_PER_STEP, 1, 4))
+        assert DEFAULT_VMEM_LIMIT_BYTES < limit <= 2 * DEFAULT_VMEM_LIMIT_BYTES
+        small = jax.ShapeDtypeStruct((8, 128), jnp.float32)
+        assert step_vmem_limit_bytes(small, block_vmem_bytes(1, 1, 4)) == DEFAULT_VMEM_LIMIT_BYTES
+
+
+@pytest.mark.parametrize("entry", ("apply", "step"))
+def test_blocked_kernel_footprint_within_budget(entry):
+    """The analysis pass prices both blocked launches at N = 2^20 from
+    their traced blocks, under the static VMEM budget."""
+    from repro.analysis import kernel_footprints
+    from repro.kernels.common import vmem_budget_bytes
+
+    r = _build("megopolis", "pallas_interpret")
+    n = 1 << 20
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32)
+    w = jax.ShapeDtypeStruct((n,), jnp.float32)
+    if entry == "apply":
+        jaxpr = jax.make_jaxpr(r.apply)(key, w, w)
+    else:
+        jaxpr = jax.make_jaxpr(lambda k, lw, p: r.step(k, lw, p, 0.5))(key, w, w)
+    (fp,) = kernel_footprints(jaxpr)
+    assert fp.grid[0] < n // TILE  # blocked: fewer grid steps than tiles
+    assert fp.vmem_bytes <= vmem_budget_bytes()

@@ -42,7 +42,12 @@ from repro.core.metrics import (
 from repro.obs.stats import stats_from_vector
 from repro.core.resamplers.batched import split_batch_keys
 from repro.core.spec import spec_for_backend
-from repro.kernels.common import MAX_VMEM_STATE, STATE_PLANE_TILE, TILE
+from repro.kernels.common import (
+    MAX_VMEM_STATE,
+    STATE_PLANE_TILE,
+    TILE,
+    unpack_state_planes,
+)
 
 N = 2 * TILE
 BATCH = 3
@@ -501,3 +506,52 @@ def test_conditional_filter_step_matches_manual_replay(base_key):
     _assert_equal(est, jnp.sum(wn * x) / jnp.sum(wn))
     fired = bool(stats.ess_norm < 0.5)
     _assert_equal(log_w1, jnp.zeros_like(lw) if fired else lw)
+
+
+# ------------------------------- 7. blocked grid: G tiles per grid step
+@pytest.mark.parametrize("state_dim", (1, 4))
+@pytest.mark.parametrize("plane_dtype", PLANE_DTYPES_TESTED)
+@pytest.mark.parametrize("table", ("r0", "rlast", "wrap"))
+@pytest.mark.parametrize("num_tiles", (1, 3, 12, 16))
+def test_blocked_step_matches_composition(num_tiles, table, plane_dtype,
+                                          state_dim):
+    """The fused step kernel, called directly at tile counts that give
+    G = 1, 1, 4 and 16 (tables and inputs of the apply parity cases),
+    equals normalise → ESS → branch → ``megopolis_ref`` bit for bit, stats
+    row included, on the branch that resamples and on the one that holds."""
+    from test_fused_apply import BLOCKED_ITERS, blocked_inputs, blocked_offsets
+
+    from repro.kernels.common import compress_plane, quantise_plane
+    from repro.kernels.megopolis.megopolis import megopolis_pallas_step, tiles_per_step
+    from repro.kernels.megopolis.ref import megopolis_ref
+
+    n = num_tiles * TILE
+    _, p, planes, state_shape = blocked_inputs(num_tiles, plane_dtype, state_dim,
+                                               jax.random.PRNGKey(num_tiles))
+    lw = quantise_plane(jax.random.normal(jax.random.PRNGKey(50 + num_tiles), (n,))
+                        * 2.0, plane_dtype)
+    lw2 = compress_plane(lw.reshape(n // 128, 128), plane_dtype)
+    g = tiles_per_step(num_tiles, planes.shape[0], lw2.dtype.itemsize)
+    offsets = blocked_offsets(table, n, g)
+    seed = jnp.array([77 + num_tiles], jnp.uint32)
+
+    @jax.jit  # as every consumer runs the composition: XLA folds the
+    def composed(lw):  # division by the constant N as it folds the kernel's
+        w = quantise_plane(normalise_log_weights(lw), plane_dtype)
+        return (effective_sample_size(lw) / jnp.float32(n), log_mean_weight(lw),
+                max_normalised_weight(lw),
+                megopolis_ref(w, offsets, seed, num_iters=BLOCKED_ITERS))
+
+    ess_n, incr, maxw, selected = composed(lw)
+    for thr, fires in ((0.7, True), (0.0, False)):
+        k2, out, stats = megopolis_pallas_step(
+            lw2, planes, offsets, seed, jnp.array([thr], jnp.float32),
+            num_iters=BLOCKED_ITERS, interpret=True)
+        assert bool(ess_n < thr) == fires
+        ancestors = selected if fires else jnp.arange(n, dtype=jnp.int32)
+        _assert_equal(k2.reshape(n), ancestors)
+        _assert_equal(unpack_state_planes(out.astype(p.dtype), state_shape),
+                      jnp.take(p, ancestors, axis=0))
+        _assert_equal(stats, jnp.stack([
+            ess_n, incr if fires else jnp.float32(0.0), jnp.float32(fires), maxw,
+        ]))

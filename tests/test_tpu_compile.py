@@ -92,6 +92,15 @@ def test_megopolis_fused_compiles_at_2_20(entry, state_dim, one_chip):
     _assert_kernel(compiled)
 
 
+def test_megopolis_apply_compiles_at_2_22(one_chip):
+    """The largest blocked launch: 4096 tiles, G at its cap, with the
+    state's own blocks beside the weights'."""
+    r = _megopolis()
+    n = 1 << 22
+    shapes = _shapes(one_chip, KEY, ((n,), jnp.float32), ((n,), jnp.float32))
+    _assert_kernel(_compile(r.apply, *shapes))
+
+
 @pytest.mark.parametrize("state_dim", [1, 4])
 @pytest.mark.parametrize("entry", ["apply_rows", "step_rows"])
 def test_megopolis_rows_compile_at_4x2_18(entry, state_dim, one_chip):
