@@ -17,6 +17,7 @@ import numpy as np
 from jax import lax
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.obs.trace import span
 from repro.reduction import block_tree_sum
 from repro.resilience.errors import VmemBudgetExceeded
 
@@ -250,7 +251,9 @@ def pack_state_planes(particles: jnp.ndarray):
 
     Plane ``d`` holds component ``d`` of every particle in the SAME flat
     row-major (R, 128) layout the weight kernels use, so ``tile_lane_ids``
-    indexes state exactly like it indexes weights.
+    indexes state exactly like it indexes weights.  Both directions run
+    under the ``resample/planes`` scope: a profile names the fused ops
+    rooted in a vector state's per-step pack and unpack by it.
     """
     n = particles.shape[0]
     state_shape = particles.shape[1:]
@@ -258,12 +261,13 @@ def pack_state_planes(particles: jnp.ndarray):
     for s in state_shape:
         d *= s
     d_pad = pad_state_dim(d)
-    flat = particles.reshape(n, d).T  # [d, N]
-    if d_pad != d:
-        flat = jnp.concatenate(
-            [flat, jnp.zeros((d_pad - d, n), flat.dtype)], axis=0
-        )
-    return flat.reshape(d_pad, n // LANES, LANES), state_shape
+    with span("resample/planes"):
+        flat = particles.reshape(n, d).T  # [d, N]
+        if d_pad != d:
+            flat = jnp.concatenate(
+                [flat, jnp.zeros((d_pad - d, n), flat.dtype)], axis=0
+            )
+        return flat.reshape(d_pad, n // LANES, LANES), state_shape
 
 
 def unpack_state_planes(planes: jnp.ndarray, state_shape) -> jnp.ndarray:
@@ -273,8 +277,9 @@ def unpack_state_planes(planes: jnp.ndarray, state_shape) -> jnp.ndarray:
     d = 1
     for s in state_shape:
         d *= s
-    out = planes.reshape(d_pad, n)[:d].T  # [N, d]
-    return out.reshape((n,) + tuple(state_shape))
+    with span("resample/planes"):
+        out = planes.reshape(d_pad, n)[:d].T  # [N, d]
+        return out.reshape((n,) + tuple(state_shape))
 
 
 def gather_state(planes: jnp.ndarray, k_global: jnp.ndarray) -> jnp.ndarray:

@@ -8,13 +8,16 @@ once metadata is stripped, are those of the unscoped code, and the
 persistent compilation cache's key leaves metadata out.  So spans are
 always on, with no switch.
 
-Two kinds are opened:
+Three kinds are opened:
 
 * the filter's stages, ``pf/predict``, ``pf/update``, ``pf/resample`` and
   ``pf/estimate`` (``pf/filter.py``);
 * one per ``Resampler`` public entry, ``dispatch_span``::
 
       family/backend/entry/plane_dtype     e.g. megopolis/pallas/step/bfloat16
+
+* ``resample/planes`` around the kernels' state-plane pack and unpack
+  (``kernels/common.py``).
 """
 
 from __future__ import annotations

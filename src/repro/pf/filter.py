@@ -19,6 +19,10 @@ name stack.
 Model callables take ``(key, x, t)``; scenario-parameterised models take a
 trailing ``theta`` pytree (``(key, x, t, theta)``), enabling per-scenario
 dynamics in the bank (see ``repro.pf.models.ungm_family``).
+
+Particles are ``[N]`` (a scalar state) or ``[N, d]`` (a vector state, e.g.
+``repro.pf.models.bearings_only``); every estimate is per component, so a
+run's estimates are ``[T]`` or ``[T, d]`` (DESIGN.md §11).
 """
 
 from __future__ import annotations
@@ -48,10 +52,10 @@ from repro.obs.trace import span
 
 @dataclasses.dataclass(frozen=True)
 class StateSpaceModel:
-    transition: Callable  # (key, x[N], t) -> x[N]
-    observe: Callable  # (key, x[], t) -> z[]       (for ground-truth sim)
-    likelihood: Callable  # (z, x[N], t) -> w[N]       (unnormalised)
-    init: Callable  # (key, n) -> x[N]
+    transition: Callable  # (key, x[N(, d)], t) -> x[N(, d)]
+    observe: Callable  # (key, x[(d)], t) -> z[]    (for ground-truth sim)
+    likelihood: Callable  # (z, x[N(, d)], t) -> w[N]  (unnormalised)
+    init: Callable  # (key, n) -> x[n(, d)]
     name: str = "model"
 
 
@@ -138,7 +142,7 @@ class ParticleFilter:
             x_bar, ancestors = self._built.apply(k_res, w, x)
         # Stage 3: estimate (uniform post-resampling weights)
         with span("pf/estimate"):
-            est = jnp.mean(x_bar)
+            est = jnp.mean(x_bar, axis=0)
         return x_bar, est, w, ancestors
 
     def step_conditional(self, key, particles, log_w, z, t, theta=None):
@@ -161,8 +165,8 @@ class ParticleFilter:
             log_w = log_w + log_weights_from_linear(w)
         # Stage 3 first: the estimate consumes the pre-resample weights
         with span("pf/estimate"):
-            wn = normalise_log_weights(log_w)
-            est = jnp.sum(wn * x) / jnp.sum(wn)
+            wn = _per_particle(normalise_log_weights(log_w), x)
+            est = jnp.sum(wn * x, axis=0) / jnp.sum(wn, axis=0)
         # Stage 2: fused normalise → ESS → conditional resample → gather
         with span("pf/resample"):
             x_bar, _, stats = self._built.step(
@@ -174,6 +178,12 @@ class ParticleFilter:
         return x_bar, log_w, est, stats
 
 
+def _per_particle(w, x):
+    """Weights ``[..., N]`` shaped to broadcast against particles ``x``
+    ``[..., N(, d)]``: unchanged for a scalar state."""
+    return w if x.ndim == w.ndim else w.reshape(w.shape + (1,) * (x.ndim - w.ndim))
+
+
 def _call(fn, *args, theta=None):
     """Invoke a model callable, appending ``theta`` only when given — keeps
     the plain ``(key, x, t)`` model API untouched."""
@@ -181,7 +191,8 @@ def _call(fn, *args, theta=None):
 
 
 def simulate(key, model: StateSpaceModel, num_steps: int, theta=None):
-    """Ground-truth trajectory + observations."""
+    """Ground-truth trajectory ``xs[T(, d)]`` and observations ``zs[T]``,
+    starting from one draw of the model's prior."""
 
     def body(carry, t):
         x, k = carry
@@ -220,7 +231,8 @@ def _alg6_step_stats(w: jnp.ndarray, ancestors: jnp.ndarray,
 def run_filter(key, pf: ParticleFilter, observations: jnp.ndarray, theta=None,
                telemetry: bool = False, with_ess: bool = False,
                checkpoint=None):
-    """Jitted scan over time; returns estimates f32[T].
+    """Jitted scan over time; returns estimates f32[T], or f32[T, d] for a
+    vector state ``[N, d]`` (the mean of each component).
 
     ``checkpoint`` (a ``repro.resilience.CheckpointPolicy``) makes the run
     crash-consistent: the time scan executes in snapshot-period chunks of
@@ -307,7 +319,8 @@ def run_filter(key, pf: ParticleFilter, observations: jnp.ndarray, theta=None,
 
 def run_filter_bank(key, pf: ParticleFilter, observations: jnp.ndarray, thetas=None,
                     telemetry: bool = False):
-    """Run S independent filters in ONE jitted scan; returns estimates f32[S, T].
+    """Run S independent filters in ONE jitted scan; returns estimates
+    f32[S, T], or f32[S, T, d] for a vector state.
 
     ``telemetry=True`` additionally returns a ``Telemetry`` record with one
     ``StepStats`` per scenario per step (every field ``[S, T]``, matching
@@ -369,7 +382,7 @@ def run_filter_bank(key, pf: ParticleFilter, observations: jnp.ndarray, thetas=N
             # pre-resample posterior, then ONE fused step_rows launch —
             # stage arithmetic mirrors step_conditional row for row.
             with span("pf/estimate"):
-                wn = normalise_log_weights(log_w, axis=-1)
+                wn = _per_particle(normalise_log_weights(log_w, axis=-1), x)
                 est = jnp.sum(wn * x, axis=1) / jnp.sum(wn, axis=1)
             with span("pf/resample"):
                 x_bar, _, stats = resampler.step_rows(
@@ -394,9 +407,9 @@ def run_filter_bank(key, pf: ParticleFilter, observations: jnp.ndarray, thetas=N
     log_w0 = jnp.zeros((num_s, pf.num_particles), jnp.float32)
     ts = jnp.arange(1, observations.shape[1] + 1, dtype=jnp.float32)
     _, out = jax.lax.scan(body, (particles, log_w0, carry_keys), (ts, observations.T))
+    # Scan stacks time first ([T, S(, d)]); swap to the [S, T(, d)] estimate
+    # layout so row s is the single filter's trajectory.
     if not telemetry:
-        return out.T
+        return jnp.swapaxes(out, 0, 1)
     ests, steps = out
-    # Scan stacks time first ([T, S] per field); transpose to the [S, T]
-    # estimate layout so row s is the single filter's trajectory.
-    return ests.T, Telemetry(steps=jax.tree.map(jnp.transpose, steps))
+    return jnp.swapaxes(ests, 0, 1), Telemetry(steps=jax.tree.map(jnp.transpose, steps))

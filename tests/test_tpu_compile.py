@@ -20,7 +20,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.spec import MegopolisSpec, spec_for_backend
 from repro.pf.filter import ParticleFilter, run_filter
-from repro.pf.models import ungm
+from repro.pf.models import bearings_only, ungm
 
 ITERS = 32
 THR = 0.5
@@ -123,6 +123,18 @@ def test_run_filter_compiles_at_2_20(ess_threshold, one_chip):
                                                 backend="pallas"))
     shapes = _shapes(one_chip, KEY, ((2,), jnp.float32))
     _assert_kernel(_compile(lambda k, z: run_filter(k, pf, z), *shapes))
+
+
+def test_bearings_run_filter_compiles_at_2_20(one_chip):
+    """A vector state through the main path: the 4-D bearings-only tracker,
+    its ``[N, 4]`` particles packed to 8 planes around the apply kernel."""
+    pf = ParticleFilter(bearings_only(), 1 << 20,
+                        resampler=MegopolisSpec(num_iters=ITERS, segment=1024,
+                                                backend="pallas"))
+    shapes = _shapes(one_chip, KEY, ((2,), jnp.float32))
+    compiled = _compile(lambda k, z: run_filter(k, pf, z), *shapes)
+    _assert_kernel(compiled)
+    assert compiled.out_info.shape == (2, 4)
 
 
 # Today's Mosaic refusals for the other families (a random-access gather
