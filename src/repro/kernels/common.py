@@ -31,13 +31,14 @@ _SUBLANES = SUBLANES
 TILE = SUBLANES * LANES  # 1024 particles per (8,128) f32 VMEM tile
 
 
-def tile_lane_ids(t) -> jnp.ndarray:
-    """Global particle index of every lane of tile ``t``: int32[8, 128] with
-    flat row-major value ``t * 1024 + row * 128 + col`` — the ONE lane->
-    particle map every kernel body shares."""
-    row = lax.broadcasted_iota(jnp.int32, (SUBLANES, LANES), 0)
-    col = lax.broadcasted_iota(jnp.int32, (SUBLANES, LANES), 1)
-    return t * TILE + row * LANES + col
+def tile_lane_ids(t, rows=SUBLANES) -> jnp.ndarray:
+    """Global particle index of every lane of block ``t`` of ``rows`` rows:
+    int32[rows, 128] with flat row-major value ``t * rows * 128 + row * 128
+    + col`` — the ONE lane->particle map every kernel body shares (one
+    (8, 128) tile by default)."""
+    row = lax.broadcasted_iota(jnp.int32, (rows, LANES), 0)
+    col = lax.broadcasted_iota(jnp.int32, (rows, LANES), 1)
+    return t * (rows * LANES) + row * LANES + col
 
 # Residency budget for kernels that keep a whole f32[N] array VMEM-resident
 # (the Metropolis/rejection random gather, the search kernel's CDF, the fused
@@ -335,10 +336,11 @@ def step_stats(lw: jnp.ndarray, n_total: int):
 
 
 def step_select(do, k_new: jnp.ndarray, t) -> jnp.ndarray:
-    """The fused step's on-chip resample branch for one output tile: the
-    freshly selected ancestors when the ESS trigger fired, else the identity
-    permutation (``tile_lane_ids``) that makes the state copy a no-op."""
-    return jnp.where(do, k_new, tile_lane_ids(t))
+    """The fused step's on-chip resample branch for output block ``t`` (one
+    tile, or the G tiles of a blocked step): the freshly selected ancestors
+    when the ESS trigger fired, else the identity permutation
+    (``tile_lane_ids``) that makes the state copy a no-op."""
+    return jnp.where(do, k_new, tile_lane_ids(t, k_new.shape[-2]))
 
 
 def gather_state_full(planes: jnp.ndarray, k_global: jnp.ndarray) -> jnp.ndarray:

@@ -121,10 +121,11 @@ DEFAULT_VMEM_LIMIT_BYTES = 16 << 20
 
 #: Tiles per iteration of the in-kernel tile loop: a partial unroll, so the
 #: scheduler may interleave the tiles' independent chains, while the code
-#: the compiler sees stays one loop body whatever G is.  At N = 2^20 on a
-#: TPU v5e, 8 takes 2%, 6% and 10-13% less kernel time than 4, 2 and 1
-#: (PERF.md §6).
-_TILE_UNROLL = 8
+#: the compiler sees stays one loop body whatever G is.  On a TPU v5e at
+#: N = 2^20, B = 32 the kernel alone takes 0.744, 0.781 and 3.247 ms (apply
+#: at 1 plane, step at 1 plane, apply at 8 planes) at 16, against 0.880,
+#: 0.906, 3.260 at 8 and 1.264, 1.268, 3.281 at 4 (PERF.md §6).
+_TILE_UNROLL = 16
 
 
 def block_vmem_bytes(g_tiles, d_pad, itemsize):
@@ -200,7 +201,12 @@ def _window_tile(lo_ref, hi_ref, s, g_tiles):
 def _for_each_tile(g_tiles, o, tile):
     """Call ``tile(g, s)`` for g = 0 .. G-1 in order: tile g of the block
     compares against tile ``s = (o // SEG) mod G + g`` of its window.  One
-    loop iteration runs ``_TILE_UNROLL`` tiles (all G when fewer)."""
+    loop iteration runs ``_TILE_UNROLL`` tiles (all G when fewer).  ``tile``
+    must not branch: a ``pl.when`` in it ends a basic block in every
+    unrolled tile, and the tiles then run one after another (on a TPU v5e
+    at 8 tiles per iteration and 1 state plane: 58 bundles per tile with
+    that branch, 33 without; 3.07 against 0.77 ms per resample at N = 2^20;
+    PERF.md §5-6)."""
     r = (o // SEG) % g_tiles
     unroll = min(_TILE_UNROLL, g_tiles)  # both powers of two
 
@@ -311,16 +317,16 @@ def _kernel_fused(offsets_ref, seed_ref, w_own_ref, w_lo_ref, w_hi_ref,
         )
         k_ref[own, :] = k_new
         wk_ref[own, :] = wk_new
-        xk_new = _carry_state(b, o, accept, x_own_ref[:, own, :].astype(cd),
-                              _window_tile(x_lo_ref, x_hi_ref, s, g_tiles).astype(cd),
-                              xk_ref[:, own, :])
-        xk_ref[:, own, :] = xk_new
-
-        @pl.when(b == pl.num_programs(1) - 1)
-        def _copy_state():
-            out_ref[:, own, :] = xk_new.astype(out_ref.dtype)
+        xk_ref[:, own, :] = _carry_state(
+            b, o, accept, x_own_ref[:, own, :].astype(cd),
+            _window_tile(x_lo_ref, x_hi_ref, s, g_tiles).astype(cd),
+            xk_ref[:, own, :])
 
     _for_each_tile(g_tiles, o, tile)
+
+    @pl.when(b == pl.num_programs(1) - 1)
+    def _copy_state():
+        out_ref[...] = xk_ref[...].astype(out_ref.dtype)
 
 
 def _kernel_fused_rows(offsets_ref, seeds_ref, w_own_ref, w_cmp_ref,
@@ -380,28 +386,27 @@ def _kernel_step(offsets_ref, seed_ref, thr_ref, lw_own_ref, lw_lo_ref,
 
     def tile(g, s):
         own = _tile_rows(g)
-        t_global = t * g_tiles + g
         w_own, w_cmp = _step_weights(
             lw_own_ref[own, :], _window_tile(lw_lo_ref, lw_hi_ref, s, g_tiles),
             st_ref[0], st_ref[2] > 0.5, n_total, lw_own_ref.dtype)
         k_new, wk_new, accept = _sweep(
-            t_global, b, o, seed_ref[0], w_own, w_cmp, k_ref[own, :],
+            t * g_tiles + g, b, o, seed_ref[0], w_own, w_cmp, k_ref[own, :],
             wk_ref[own, :], n_total,
         )
         k_ref[own, :] = k_new
         wk_ref[own, :] = wk_new
-        x_own = x_own_ref[:, own, :].astype(cd)
-        xk_new = _carry_state(b, o, accept, x_own,
-                              _window_tile(x_lo_ref, x_hi_ref, s, g_tiles).astype(cd),
-                              xk_ref[:, own, :])
-        xk_ref[:, own, :] = xk_new
-
-        @pl.when(b == pl.num_programs(1) - 1)
-        def _commit():
-            k_ref[own, :] = step_select(do, k_new, t_global)
-            out_ref[:, own, :] = jnp.where(do, xk_new, x_own).astype(out_ref.dtype)
+        xk_ref[:, own, :] = _carry_state(
+            b, o, accept, x_own_ref[:, own, :].astype(cd),
+            _window_tile(x_lo_ref, x_hi_ref, s, g_tiles).astype(cd),
+            xk_ref[:, own, :])
 
     _for_each_tile(g_tiles, o, tile)
+
+    @pl.when(b == pl.num_programs(1) - 1)
+    def _commit():
+        k_ref[...] = step_select(do, k_ref[...], t)
+        out_ref[...] = jnp.where(do, xk_ref[...],
+                                 x_own_ref[...].astype(cd)).astype(out_ref.dtype)
 
 
 def _kernel_step_rows(offsets_ref, seeds_ref, thr_ref, lw_own_ref, lw_cmp_ref,

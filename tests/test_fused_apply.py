@@ -467,6 +467,44 @@ def test_blocked_apply_matches_ref(num_tiles, table, plane_dtype, state_dim):
                   jnp.take(p, ancestors, axis=0))
 
 
+@pytest.mark.parametrize("state_dim", (1, 4))
+@pytest.mark.parametrize("entry", ("apply", "step"))
+def test_blocked_tile_loop_has_no_branch(entry, state_dim):
+    """The G-tile loop of both blocked fused kernels holds no branch, so
+    its unrolled tiles are one block the scheduler may interleave; the
+    last iteration's write is the one ``cond`` after the loop, once per
+    grid step.  The step's prelude is the only ``cond`` before it."""
+    from repro.analysis.walker import count_primitive, pallas_call_eqns, subjaxprs
+    from repro.kernels.megopolis.megopolis import (
+        megopolis_pallas_fused,
+        megopolis_pallas_step,
+        tiles_per_step,
+    )
+
+    num_tiles = 16
+    rows = num_tiles * TILE // 128
+    d_pad = pad_state_dim(state_dim)
+    assert tiles_per_step(num_tiles, d_pad, 4) == BLOCKED_G[num_tiles] > 1
+    w = jax.ShapeDtypeStruct((rows, 128), jnp.float32)
+    planes = jax.ShapeDtypeStruct((d_pad, rows, 128), jnp.float32)
+    offsets = jax.ShapeDtypeStruct((BLOCKED_ITERS,), jnp.int32)
+    seed = jax.ShapeDtypeStruct((1,), jnp.uint32)
+    if entry == "apply":
+        jaxpr = jax.make_jaxpr(lambda *a: megopolis_pallas_fused(
+            *a, num_iters=BLOCKED_ITERS, interpret=True))(w, planes, offsets, seed)
+    else:
+        thr = jax.ShapeDtypeStruct((1,), jnp.float32)
+        jaxpr = jax.make_jaxpr(lambda *a: megopolis_pallas_step(
+            *a, num_iters=BLOCKED_ITERS, interpret=True))(w, planes, offsets, seed, thr)
+    ((call, _),) = pallas_call_eqns(jaxpr)
+    kernel = call.params["jaxpr"]
+    (loop,) = [i for i, e in enumerate(kernel.eqns) if e.primitive.name in ("scan", "while")]
+    assert sum(count_primitive(sub, "cond") for _, sub in subjaxprs(kernel.eqns[loop])) == 0
+    conds = [i for i, e in enumerate(kernel.eqns) if e.primitive.name == "cond"]
+    assert [i > loop for i in conds] == ([True] if entry == "apply" else [False, True])
+    assert count_primitive(kernel, "cond") == len(conds)
+
+
 @pytest.mark.parametrize("entry", ("apply", "step"))
 def test_tiles_per_step_at_cell_shapes(entry):
     """G at the benchmark cells' shape (N = 2^20, one f32 state plane) is
