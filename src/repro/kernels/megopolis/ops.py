@@ -1,4 +1,4 @@
-"""Public jit'd wrapper for the Megopolis TPU kernel.
+"""Public jit'd wrappers for the Megopolis TPU kernel.
 
 Accepts the same ``(key, weights, num_iters)`` signature as the reference
 resamplers in ``repro.core``.  Alignment contract: ``N % 1024 == 0`` (one
@@ -6,6 +6,11 @@ f32 VMEM tile); production particle counts are powers of two well above
 this (the paper sweeps 2^6..2^22), and the wrapper raises a clear error
 otherwise rather than silently padding (padding would perturb the
 uniform-offset distribution over [0, N)).
+
+Every entry launches the one bank grid of ``megopolis.py``; they differ in
+how the keys become offset tables and seeds, ``(offsets2d int32[S, B],
+seeds uint32[S])``: one key (``_one_key``, a one-row bank), one table shared
+by a bank (``_shared_table``), or a key per row (``_row_keys``).
 """
 
 from __future__ import annotations
@@ -27,13 +32,58 @@ from repro.kernels.common import (
 )
 from repro.kernels.megopolis.megopolis import (
     LANES,
-    megopolis_pallas,
-    megopolis_pallas_batch,
     megopolis_pallas_fused,
-    megopolis_pallas_fused_rows,
     megopolis_pallas_step,
-    megopolis_pallas_step_rows,
 )
+
+
+def _check_aligned(n, who):
+    if n % TILE != 0:
+        raise ValueError(
+            f"{who} requires N % {TILE} == 0 (one f32 VMEM tile); got N={n}. "
+            "Use the reference or xla backend (repro.core) for unaligned N."
+        )
+
+
+def _check_bank(weights, who):
+    """``(B, N)`` of a ``[B, N]`` bank, or a ValueError."""
+    if weights.ndim != 2:
+        raise ValueError(f"{who} expects a [B, N] bank; got shape {weights.shape}")
+    _check_aligned(weights.shape[1], who)
+    return weights.shape
+
+
+def _one_key(key, n, num_iters):
+    """The single call's offset table and seed, as a one-row bank."""
+    key_off, key_seed = jax.random.split(key)
+    offsets = jax.random.randint(key_off, (num_iters,), 0, n, dtype=jnp.int32)
+    return offsets[None], key_to_seed(key_seed).reshape(1)
+
+
+def _shared_table(key, bsz, n, num_iters):
+    """One offset table drawn ONCE and shared by every row (the bank-level
+    lift of Alg. 5's shared offset, DESIGN.md §4), a seed per row, so rows
+    stay statistically independent."""
+    key_off, key_rows = jax.random.split(key)
+    offsets = jax.random.randint(key_off, (num_iters,), 0, n, dtype=jnp.int32)
+    return (jnp.broadcast_to(offsets, (bsz, num_iters)),
+            key_to_seed(jax.random.split(key_rows, bsz)))
+
+
+def _row_keys(keys, n, num_iters):
+    """A key per row: row s's table and seed are the single call's with
+    ``keys[s]``, so row s is bit-identical to that call."""
+    offsets, seeds = jax.vmap(lambda k: _one_key(k, n, num_iters))(keys)
+    return offsets[:, 0], seeds[:, 0]
+
+
+def _ancestors(weights, tables, num_iters, interpret, plane_dtype, name):
+    """Ancestors of a ``[S, N]`` bank: the kernel without state planes."""
+    bsz, n = weights.shape
+    w3 = compress_plane(weights.reshape(bsz, n // LANES, LANES), plane_dtype)
+    (k3,) = megopolis_pallas_fused(w3, None, *tables, num_iters=num_iters,
+                                   interpret=interpret, name=name)
+    return k3.reshape(bsz, n)
 
 
 def megopolis_tpu(
@@ -51,17 +101,9 @@ def megopolis_tpu(
     TPU.  There is no default: every caller names the mode it runs.
     """
     n = weights.shape[0]
-    if n % TILE != 0:
-        raise ValueError(
-            f"megopolis_tpu requires N % {TILE} == 0 (one f32 VMEM tile); got N={n}. "
-            "Use repro.core.megopolis for unaligned N."
-        )
-    key_off, key_seed = jax.random.split(key)
-    offsets = jax.random.randint(key_off, (num_iters,), 0, n, dtype=jnp.int32)
-    seed = key_to_seed(key_seed).reshape(1)
-    w2 = compress_plane(weights.reshape(n // LANES, LANES), plane_dtype)
-    k2 = megopolis_pallas(w2, offsets, seed, num_iters=num_iters, interpret=interpret)
-    return k2.reshape(n)
+    _check_aligned(n, "megopolis_tpu")
+    return _ancestors(weights[None], _one_key(key, n, num_iters), num_iters,
+                      interpret, plane_dtype, "megopolis_pallas")[0]
 
 
 def megopolis_tpu_batch(
@@ -72,27 +114,13 @@ def megopolis_tpu_batch(
     interpret: bool,
     plane_dtype="float32",
 ) -> jnp.ndarray:
-    """Resample a ``[B, N]`` weight bank in one kernel launch (DESIGN.md §4).
-
-    The global offset table is drawn ONCE and shared by every row (the
-    bank-level lift of Alg. 5's shared offset — one scalar-prefetch schedule
-    for the whole launch); each row gets its own stateless-RNG seed, so rows
-    stay statistically independent.  Returns int32[B, N] ancestors.
-    """
-    if weights.ndim != 2:
-        raise ValueError(f"megopolis_tpu_batch expects weights[B, N]; got {weights.shape}")
-    bsz, n = weights.shape
-    if n % TILE != 0:
-        raise ValueError(
-            f"megopolis_tpu_batch requires N % {TILE} == 0 (one f32 VMEM tile); got N={n}. "
-            "Use repro.core.megopolis_batch for unaligned N."
-        )
-    key_off, key_rows = jax.random.split(key)
-    offsets = jax.random.randint(key_off, (num_iters,), 0, n, dtype=jnp.int32)
-    seeds = key_to_seed(jax.random.split(key_rows, bsz))
-    w3 = compress_plane(weights.reshape(bsz, n // LANES, LANES), plane_dtype)
-    k3 = megopolis_pallas_batch(w3, offsets, seeds, num_iters=num_iters, interpret=interpret)
-    return k3.reshape(bsz, n)
+    """Resample a ``[B, N]`` weight bank in one kernel launch (DESIGN.md §4)
+    under one offset table shared by every row (``_shared_table``: one
+    scalar-prefetch schedule for the whole launch).  Returns int32[B, N]
+    ancestors."""
+    bsz, n = _check_bank(weights, "megopolis_tpu_batch")
+    return _ancestors(weights, _shared_table(key, bsz, n, num_iters), num_iters,
+                      interpret, plane_dtype, "megopolis_pallas_batch")
 
 
 def megopolis_tpu_apply(
@@ -110,22 +138,16 @@ def megopolis_tpu_apply(
     ``particles``: ``[N]`` or ``[N, ...]`` (trailing dims are the state).
     Returns ``(particles' , ancestors)``."""
     n = weights.shape[0]
-    if n % TILE != 0:
-        raise ValueError(
-            f"megopolis_tpu_apply requires N % {TILE} == 0 (one f32 VMEM tile); got N={n}."
-        )
+    _check_aligned(n, "megopolis_tpu_apply")
     state_dim_of(particles, n, "megopolis_tpu_apply")  # validates the particle axis
-    key_off, key_seed = jax.random.split(key)
-    offsets = jax.random.randint(key_off, (num_iters,), 0, n, dtype=jnp.int32)
-    seed = key_to_seed(key_seed).reshape(1)
-    w2 = compress_plane(weights.reshape(n // LANES, LANES), plane_dtype)
+    offsets, seed = _one_key(key, n, num_iters)
+    w3 = compress_plane(weights.reshape(1, n // LANES, LANES), plane_dtype)
     planes, state_shape = pack_state_planes(particles)
     planes = compress_plane(planes, plane_dtype)
-    k2, out = megopolis_pallas_fused(
-        w2, planes, offsets, seed, num_iters=num_iters, interpret=interpret
-    )
-    out = out.astype(particles.dtype)
-    return unpack_state_planes(out, state_shape), k2.reshape(n)
+    k3, out = megopolis_pallas_fused(w3, planes[None], offsets, seed,
+                                     num_iters=num_iters, interpret=interpret)
+    out = out[0].astype(particles.dtype)
+    return unpack_state_planes(out, state_shape), k3.reshape(n)
 
 
 def megopolis_tpu_apply_batch(
@@ -141,23 +163,15 @@ def megopolis_tpu_apply_batch(
     offset table is drawn ONCE (same key derivation) and shared by every
     row, per-row RNG seeds.  ``particles``: ``[B, N, ...]``.  Returns
     ``(particles'[B, N, ...], ancestors int32[B, N])``."""
-    if weights.ndim != 2:
-        raise ValueError(
-            f"megopolis_tpu_apply_batch expects weights[B, N]; got {weights.shape}"
-        )
-    bsz, n = weights.shape
-    if n % TILE != 0:
-        raise ValueError(
-            f"megopolis_tpu_apply_batch requires N % {TILE} == 0; got N={n}."
-        )
-    key_off, key_rows = jax.random.split(key)
-    offsets = jax.random.randint(key_off, (num_iters,), 0, n, dtype=jnp.int32)
-    offsets2d = jnp.broadcast_to(offsets[None, :], (bsz, num_iters))
-    seeds = key_to_seed(jax.random.split(key_rows, bsz))
-    return _apply_rows_launch(weights, particles, offsets2d, seeds,
-                              num_iters=num_iters, interpret=interpret,
-                              who="megopolis_tpu_apply_batch",
-                              plane_dtype=plane_dtype)
+    bsz, n = _check_bank(weights, "megopolis_tpu_apply_batch")
+    tables = _shared_table(key, bsz, n, num_iters)
+    return run_fused_bank(
+        lambda w3, planes: megopolis_pallas_fused(
+            w3, planes, *tables, num_iters=num_iters, interpret=interpret,
+            name="megopolis_pallas_apply_rows"),
+        weights, particles, "megopolis_tpu_apply_batch",
+        plane_dtype=plane_dtype, state_resident=False,
+    )
 
 
 def megopolis_tpu_apply_rows(
@@ -172,36 +186,24 @@ def megopolis_tpu_apply_rows(
     """Fused bank launch over EXPLICIT per-row keys (the filter-bank path):
     each row derives its own offset table and seed exactly as the single
     ``megopolis_tpu_apply`` would, so row b is bit-identical to the single
-    call with ``keys[b]`` — in ONE leading-batch-grid launch."""
-    if weights.ndim != 2:
-        raise ValueError(
-            f"megopolis_tpu_apply_rows expects weights[B, N]; got {weights.shape}"
-        )
-    bsz, n = weights.shape
-    if n % TILE != 0:
-        raise ValueError(
-            f"megopolis_tpu_apply_rows requires N % {TILE} == 0; got N={n}."
-        )
-    split = jax.vmap(jax.random.split)(keys)
-    keys_off, keys_seed = split[:, 0], split[:, 1]
-    offsets2d = jax.vmap(
-        lambda k: jax.random.randint(k, (num_iters,), 0, n, dtype=jnp.int32)
-    )(keys_off)
-    seeds = key_to_seed(keys_seed)
-    return _apply_rows_launch(weights, particles, offsets2d, seeds,
-                              num_iters=num_iters, interpret=interpret,
-                              who="megopolis_tpu_apply_rows",
-                              plane_dtype=plane_dtype)
-
-
-def _apply_rows_launch(weights, particles, offsets2d, seeds, *, num_iters,
-                       interpret, who, plane_dtype="float32"):
+    call with ``keys[b]`` — in ONE launch."""
+    _, n = _check_bank(weights, "megopolis_tpu_apply_rows")
+    tables = _row_keys(keys, n, num_iters)
     return run_fused_bank(
-        lambda w3, planes: megopolis_pallas_fused_rows(
-            w3, planes, offsets2d, seeds, num_iters=num_iters, interpret=interpret
-        ),
-        weights, particles, who, plane_dtype=plane_dtype, state_resident=False,
+        lambda w3, planes: megopolis_pallas_fused(
+            w3, planes, *tables, num_iters=num_iters, interpret=interpret,
+            name="megopolis_pallas_apply_rows"),
+        weights, particles, "megopolis_tpu_apply_rows",
+        plane_dtype=plane_dtype, state_resident=False,
     )
+
+
+def _check_step(n, who, plane_dtype):
+    _check_aligned(n, who)
+    check_vmem_resident(n, who, "log-weight array",
+                        remedy=f"Compose Resampler.{who.removeprefix('megopolis_tpu_')} "
+                               "on the reference/xla backend above this size.",
+                        itemsize=plane_itemsize(plane_dtype))
 
 
 def megopolis_tpu_step(
@@ -222,27 +224,17 @@ def megopolis_tpu_step(
     Returns ``(particles', ancestors, stats f32[4])`` with ``stats`` =
     (ess_norm, log_evidence_incr, resampled, max_weight) — DESIGN.md §15."""
     n = log_weights.shape[0]
-    if n % TILE != 0:
-        raise ValueError(
-            f"megopolis_tpu_step requires N % {TILE} == 0 (one f32 VMEM tile); got N={n}."
-        )
-    check_vmem_resident(n, "megopolis_tpu_step", "log-weight array",
-                        remedy="Compose Resampler.step on the reference/xla backend "
-                               "above this size.",
-                        itemsize=plane_itemsize(plane_dtype))
+    _check_step(n, "megopolis_tpu_step", plane_dtype)
     state_dim_of(particles, n, "megopolis_tpu_step")  # validates the particle axis
-    key_off, key_seed = jax.random.split(key)
-    offsets = jax.random.randint(key_off, (num_iters,), 0, n, dtype=jnp.int32)
-    seed = key_to_seed(key_seed).reshape(1)
+    offsets, seed = _one_key(key, n, num_iters)
     thr = jnp.asarray(ess_threshold, jnp.float32).reshape(1)
-    lw2 = compress_plane(log_weights.reshape(n // LANES, LANES), plane_dtype)
+    lw3 = compress_plane(log_weights.reshape(1, n // LANES, LANES), plane_dtype)
     planes, state_shape = pack_state_planes(particles)
     planes = compress_plane(planes, plane_dtype)
-    k2, out, stats = megopolis_pallas_step(
-        lw2, planes, offsets, seed, thr, num_iters=num_iters, interpret=interpret
-    )
-    out = out.astype(particles.dtype)
-    return unpack_state_planes(out, state_shape), k2.reshape(n), stats
+    k3, out, stats = megopolis_pallas_step(
+        lw3, planes[None], offsets, seed, thr, num_iters=num_iters, interpret=interpret)
+    out = out[0].astype(particles.dtype)
+    return unpack_state_planes(out, state_shape), k3.reshape(n), stats[0]
 
 
 def megopolis_tpu_step_rows(
@@ -257,34 +249,17 @@ def megopolis_tpu_step_rows(
 ):
     """Fused SMC-step bank over EXPLICIT per-row keys: row b is
     bit-identical to ``megopolis_tpu_step(keys[b], ...)`` — each row takes
-    its own on-chip resample decision in ONE leading-batch-grid launch.
+    its own on-chip resample decision in ONE launch.
     Returns ``(particles'[B, N, ...], ancestors int32[B, N],
     stats f32[B, 4])``."""
-    if log_weights.ndim != 2:
-        raise ValueError(
-            f"megopolis_tpu_step_rows expects log_weights[B, N]; got {log_weights.shape}"
-        )
-    bsz, n = log_weights.shape
-    if n % TILE != 0:
-        raise ValueError(
-            f"megopolis_tpu_step_rows requires N % {TILE} == 0; got N={n}."
-        )
-    check_vmem_resident(n, "megopolis_tpu_step_rows", "log-weight array",
-                        remedy="Compose Resampler.step_rows on the reference/xla "
-                               "backend above this size.",
-                        itemsize=plane_itemsize(plane_dtype))
-    split = jax.vmap(jax.random.split)(keys)
-    keys_off, keys_seed = split[:, 0], split[:, 1]
-    offsets2d = jax.vmap(
-        lambda k: jax.random.randint(k, (num_iters,), 0, n, dtype=jnp.int32)
-    )(keys_off)
-    seeds = key_to_seed(keys_seed)
+    _, n = _check_bank(log_weights, "megopolis_tpu_step_rows")
+    _check_step(n, "megopolis_tpu_step_rows", plane_dtype)
     thr = jnp.asarray(ess_threshold, jnp.float32).reshape(1)
+    tables = _row_keys(keys, n, num_iters)
     return run_step_bank(
-        lambda lw3, planes: megopolis_pallas_step_rows(
-            lw3, planes, offsets2d, seeds, thr, num_iters=num_iters,
-            interpret=interpret
-        ),
+        lambda lw3, planes: megopolis_pallas_step(
+            lw3, planes, *tables, thr, num_iters=num_iters, interpret=interpret,
+            name="megopolis_pallas_step_rows"),
         log_weights, particles, "megopolis_tpu_step_rows",
         plane_dtype=plane_dtype, state_resident=False,
     )

@@ -23,7 +23,7 @@ from repro.core import (
 )
 from repro.core.resamplers.batched import split_batch_keys
 from repro.kernels.common import TILE, key_to_seed
-from repro.kernels.megopolis.megopolis import megopolis_pallas, megopolis_pallas_batch
+from repro.kernels.megopolis.megopolis import megopolis_pallas_fused
 from repro.kernels.megopolis.ops import megopolis_tpu_batch
 from repro.kernels.megopolis.ref import megopolis_ref
 from repro.pf import ParticleFilter, run_filter, run_filter_bank, ungm, ungm_family, ungm_theta
@@ -100,9 +100,11 @@ def test_megopolis_kernel_batch_matches_vmapped_ref(n_tiles, num_iters, base_key
     w = jax.random.uniform(jax.random.fold_in(base_key, 21), (bsz, n)) + 1e-3
     offsets = jax.random.randint(jax.random.fold_in(base_key, 22), (num_iters,), 0, n, jnp.int32)
     seeds = key_to_seed(jax.random.split(jax.random.fold_in(base_key, 23), bsz))
-    got = megopolis_pallas_batch(
-        w.reshape(bsz, -1, 128), offsets, seeds, num_iters=num_iters, interpret=True
-    ).reshape(bsz, n)
+    (got,) = megopolis_pallas_fused(
+        w.reshape(bsz, -1, 128), None, jnp.broadcast_to(offsets, (bsz, num_iters)), seeds,
+        num_iters=num_iters, interpret=True, name="megopolis_pallas_batch",
+    )
+    got = got.reshape(bsz, n)
     want = jax.vmap(
         lambda wr, s: megopolis_ref(wr, offsets, s.reshape(1), num_iters=num_iters)
     )(w, seeds)
@@ -114,15 +116,16 @@ def test_megopolis_kernel_batch_rows_match_single_bank_kernel(base_key):
     w = jax.random.uniform(jax.random.fold_in(base_key, 24), (bsz, n)) + 1e-3
     offsets = jax.random.randint(jax.random.fold_in(base_key, 25), (num_iters,), 0, n, jnp.int32)
     seeds = key_to_seed(jax.random.split(jax.random.fold_in(base_key, 26), bsz))
-    got = megopolis_pallas_batch(
-        w.reshape(bsz, -1, 128), offsets, seeds, num_iters=num_iters, interpret=True
+    (got,) = megopolis_pallas_fused(
+        w.reshape(bsz, -1, 128), None, jnp.broadcast_to(offsets, (bsz, num_iters)), seeds,
+        num_iters=num_iters, interpret=True, name="megopolis_pallas_batch",
     )
     for s in range(bsz):
-        single = megopolis_pallas(
-            w[s].reshape(-1, 128), offsets, seeds[s].reshape(1),
-            num_iters=num_iters, interpret=True,
+        (single,) = megopolis_pallas_fused(
+            w[s].reshape(1, -1, 128), None, offsets[None], seeds[s].reshape(1),
+            num_iters=num_iters, interpret=True, name="megopolis_pallas",
         )
-        np.testing.assert_array_equal(np.asarray(got[s]), np.asarray(single))
+        np.testing.assert_array_equal(np.asarray(got[s]), np.asarray(single[0]))
 
 
 def test_megopolis_tpu_batch_public_api(base_key):

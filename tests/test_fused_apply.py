@@ -436,14 +436,32 @@ def blocked_inputs(num_tiles, plane_dtype, state_dim, key):
     return w, p, compress_plane(planes, plane_dtype), state_shape
 
 
+#: The two rows' tables of the ``bank`` cases: a two-row launch whose rows
+#: split their comparison windows at different tiles.
+BANK_TABLES = ("rlast", "wrap")
+
+#: ``(num_tiles, table, plane_dtype)`` of the blocked parity cases: every
+#: single-row table at every G, and a two-row ``bank`` at G = 4 and 16.
+BLOCKED_CASES = [(num_tiles, table, plane_dtype)
+                 for num_tiles in sorted(BLOCKED_G)
+                 for table in ("r0", "rlast", "wrap")
+                 for plane_dtype in PLANE_DTYPES_TESTED]
+BLOCKED_CASES += [(12, "bank", "float32"), (16, "bank", "float32")]
+
+
+def blocked_tables(table):
+    """The table name of each row of a blocked parity case."""
+    return BANK_TABLES if table == "bank" else (table,)
+
+
 @pytest.mark.parametrize("state_dim", (1, 4))
-@pytest.mark.parametrize("plane_dtype", PLANE_DTYPES_TESTED)
-@pytest.mark.parametrize("table", ("r0", "rlast", "wrap"))
-@pytest.mark.parametrize("num_tiles", sorted(BLOCKED_G))
+@pytest.mark.parametrize("num_tiles,table,plane_dtype", BLOCKED_CASES)
 def test_blocked_apply_matches_ref(num_tiles, table, plane_dtype, state_dim):
     """The fused apply kernel, called directly at tile counts that give
     G = 1, 1, 4 and 16, equals the ``megopolis_ref`` oracle and the state
-    gather of its ancestors bit for bit, wherever the window starts."""
+    gather of its ancestors bit for bit, wherever the window starts.  Each
+    row of a two-row bank, with its own weights, state, table and seed,
+    equals the one-row launch on that row's inputs."""
     from repro.kernels.common import compress_plane
     from repro.kernels.megopolis.megopolis import (
         megopolis_pallas_fused,
@@ -452,28 +470,40 @@ def test_blocked_apply_matches_ref(num_tiles, table, plane_dtype, state_dim):
     from repro.kernels.megopolis.ref import megopolis_ref
 
     n = num_tiles * TILE
-    w, p, planes, state_shape = blocked_inputs(num_tiles, plane_dtype, state_dim,
-                                               jax.random.PRNGKey(num_tiles))
-    w2 = compress_plane(w.reshape(n // 128, 128), plane_dtype)
-    g = tiles_per_step(num_tiles, planes.shape[0], w2.dtype.itemsize)
+    tables = blocked_tables(table)
+    rows = [blocked_inputs(num_tiles, plane_dtype, state_dim,
+                           jax.random.PRNGKey(num_tiles + 100 * s))
+            for s in range(len(tables))]
+    w3 = compress_plane(jnp.stack([w.reshape(n // 128, 128) for w, *_ in rows]),
+                        plane_dtype)
+    planes = jnp.stack([planes for _, _, planes, _ in rows])
+    g = tiles_per_step(num_tiles, planes.shape[1], w3.dtype.itemsize)
     assert g == BLOCKED_G[num_tiles]
-    offsets = blocked_offsets(table, n, g)
-    seed = jnp.array([2021 + num_tiles], jnp.uint32)
-    k2, out = megopolis_pallas_fused(w2, planes, offsets, seed,
+    offsets = jnp.stack([blocked_offsets(t, n, g) for t in tables])
+    seeds = jnp.arange(len(tables), dtype=jnp.uint32) + 2021 + num_tiles
+    k3, out = megopolis_pallas_fused(w3, planes, offsets, seeds,
                                      num_iters=BLOCKED_ITERS, interpret=True)
-    ancestors = megopolis_ref(w, offsets, seed, num_iters=BLOCKED_ITERS)
-    _assert_equal(k2.reshape(n), ancestors)
-    _assert_equal(unpack_state_planes(out.astype(p.dtype), state_shape),
-                  jnp.take(p, ancestors, axis=0))
+    for s, (w, p, _, state_shape) in enumerate(rows):
+        ancestors = megopolis_ref(w, offsets[s], seeds[s:s + 1], num_iters=BLOCKED_ITERS)
+        _assert_equal(k3[s].reshape(n), ancestors)
+        _assert_equal(unpack_state_planes(out[s].astype(p.dtype), state_shape),
+                      jnp.take(p, ancestors, axis=0))
+        if len(rows) > 1:
+            one = megopolis_pallas_fused(w3[s:s + 1], planes[s:s + 1], offsets[s:s + 1],
+                                         seeds[s:s + 1], num_iters=BLOCKED_ITERS,
+                                         interpret=True)
+            _assert_equal(k3[s], one[0][0])
+            _assert_equal(out[s], one[1][0])
 
 
 @pytest.mark.parametrize("state_dim", (1, 4))
-@pytest.mark.parametrize("entry", ("apply", "step"))
+@pytest.mark.parametrize("entry", ("apply", "step", "apply_rows", "step_rows"))
 def test_blocked_tile_loop_has_no_branch(entry, state_dim):
-    """The G-tile loop of both blocked fused kernels holds no branch, so
-    its unrolled tiles are one block the scheduler may interleave; the
-    last iteration's write is the one ``cond`` after the loop, once per
-    grid step.  The step's prelude is the only ``cond`` before it."""
+    """The G-tile loop of both blocked fused kernels, single and bank,
+    holds no branch, so its unrolled tiles are one block the scheduler may
+    interleave; the last iteration's write is the one ``cond`` after the
+    loop, once per grid step.  The step's prelude is the only ``cond``
+    before it."""
     from repro.analysis.walker import count_primitive, pallas_call_eqns, subjaxprs
     from repro.kernels.megopolis.megopolis import (
         megopolis_pallas_fused,
@@ -482,26 +512,31 @@ def test_blocked_tile_loop_has_no_branch(entry, state_dim):
     )
 
     num_tiles = 16
+    bsz = 2 if entry.endswith("_rows") else 1
     rows = num_tiles * TILE // 128
     d_pad = pad_state_dim(state_dim)
     assert tiles_per_step(num_tiles, d_pad, 4) == BLOCKED_G[num_tiles] > 1
-    w = jax.ShapeDtypeStruct((rows, 128), jnp.float32)
-    planes = jax.ShapeDtypeStruct((d_pad, rows, 128), jnp.float32)
-    offsets = jax.ShapeDtypeStruct((BLOCKED_ITERS,), jnp.int32)
-    seed = jax.ShapeDtypeStruct((1,), jnp.uint32)
-    if entry == "apply":
+    w = jax.ShapeDtypeStruct((bsz, rows, 128), jnp.float32)
+    planes = jax.ShapeDtypeStruct((bsz, d_pad, rows, 128), jnp.float32)
+    offsets = jax.ShapeDtypeStruct((bsz, BLOCKED_ITERS), jnp.int32)
+    seeds = jax.ShapeDtypeStruct((bsz,), jnp.uint32)
+    name = f"megopolis_pallas_{entry}"
+    if entry.startswith("apply"):
         jaxpr = jax.make_jaxpr(lambda *a: megopolis_pallas_fused(
-            *a, num_iters=BLOCKED_ITERS, interpret=True))(w, planes, offsets, seed)
+            *a, num_iters=BLOCKED_ITERS, interpret=True, name=name))(
+                w, planes, offsets, seeds)
     else:
         thr = jax.ShapeDtypeStruct((1,), jnp.float32)
         jaxpr = jax.make_jaxpr(lambda *a: megopolis_pallas_step(
-            *a, num_iters=BLOCKED_ITERS, interpret=True))(w, planes, offsets, seed, thr)
+            *a, num_iters=BLOCKED_ITERS, interpret=True, name=name))(
+                w, planes, offsets, seeds, thr)
     ((call, _),) = pallas_call_eqns(jaxpr)
+    assert call.params["name"] == name
     kernel = call.params["jaxpr"]
     (loop,) = [i for i, e in enumerate(kernel.eqns) if e.primitive.name in ("scan", "while")]
     assert sum(count_primitive(sub, "cond") for _, sub in subjaxprs(kernel.eqns[loop])) == 0
     conds = [i for i, e in enumerate(kernel.eqns) if e.primitive.name == "cond"]
-    assert [i > loop for i in conds] == ([True] if entry == "apply" else [False, True])
+    assert [i > loop for i in conds] == ([True] if entry.startswith("apply") else [False, True])
     assert count_primitive(kernel, "cond") == len(conds)
 
 
@@ -554,5 +589,5 @@ def test_blocked_kernel_footprint_within_budget(entry):
     else:
         jaxpr = jax.make_jaxpr(lambda k, lw, p: r.step(k, lw, p, 0.5))(key, w, w)
     (fp,) = kernel_footprints(jaxpr)
-    assert fp.grid[0] < n // TILE  # blocked: fewer grid steps than tiles
+    assert fp.grid[0] == 1 and fp.grid[1] < n // TILE  # blocked: fewer grid steps than tiles
     assert fp.vmem_bytes <= vmem_budget_bytes()

@@ -13,7 +13,7 @@ from repro.core.resamplers.batched import split_batch_keys
 from repro.core.weightgen import gaussian_weights
 from repro.kernels import megopolis_tpu, metropolis_tpu, prefix_sum_tpu
 from repro.kernels.common import TILE, flat_roll, hash_uniform, key_to_seed
-from repro.kernels.megopolis.megopolis import megopolis_pallas
+from repro.kernels.megopolis.megopolis import megopolis_pallas_fused
 from repro.kernels.megopolis.ref import megopolis_ref
 from repro.kernels.metropolis.c1c2 import metropolis_c1_pallas, metropolis_c2_pallas
 from repro.kernels.metropolis.metropolis import metropolis_pallas
@@ -56,9 +56,11 @@ def test_megopolis_kernel_matches_ref(n_tiles, num_iters, dtype, base_key):
     w = (jax.random.uniform(jax.random.fold_in(base_key, n_tiles), (n,)) + 1e-3).astype(dtype)
     offsets = jax.random.randint(jax.random.fold_in(base_key, 77), (num_iters,), 0, n, jnp.int32)
     seed = key_to_seed(jax.random.fold_in(base_key, 99)).reshape(1)
-    got = megopolis_pallas(
-        w.reshape(-1, 128), offsets, seed, num_iters=num_iters, interpret=True
-    ).reshape(n)
+    (got,) = megopolis_pallas_fused(
+        w.reshape(1, -1, 128), None, offsets[None], seed, num_iters=num_iters,
+        interpret=True, name="megopolis_pallas",
+    )
+    got = got.reshape(n)
     want = megopolis_ref(w, offsets, seed, num_iters=num_iters)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 

@@ -48,6 +48,13 @@ from repro.kernels.common import (
     TILE,
     unpack_state_planes,
 )
+from test_fused_apply import (
+    BLOCKED_CASES,
+    BLOCKED_ITERS,
+    blocked_inputs,
+    blocked_offsets,
+    blocked_tables,
+)
 
 N = 2 * TILE
 BATCH = 3
@@ -510,48 +517,61 @@ def test_conditional_filter_step_matches_manual_replay(base_key):
 
 # ------------------------------- 7. blocked grid: G tiles per grid step
 @pytest.mark.parametrize("state_dim", (1, 4))
-@pytest.mark.parametrize("plane_dtype", PLANE_DTYPES_TESTED)
-@pytest.mark.parametrize("table", ("r0", "rlast", "wrap"))
-@pytest.mark.parametrize("num_tiles", (1, 3, 12, 16))
+@pytest.mark.parametrize("num_tiles,table,plane_dtype", BLOCKED_CASES)
 def test_blocked_step_matches_composition(num_tiles, table, plane_dtype,
                                           state_dim):
     """The fused step kernel, called directly at tile counts that give
     G = 1, 1, 4 and 16 (tables and inputs of the apply parity cases),
     equals normalise → ESS → branch → ``megopolis_ref`` bit for bit, stats
-    row included, on the branch that resamples and on the one that holds."""
-    from test_fused_apply import BLOCKED_ITERS, blocked_inputs, blocked_offsets
-
+    row included, on the branch that resamples and on the one that holds.
+    A two-row bank takes each row's own decision (the second row's flat
+    log-weights hold at 0.7, where the first's resample; both resample at
+    1.0), and each of its rows equals the one-row launch on that row's
+    inputs."""
     from repro.kernels.common import compress_plane, quantise_plane
     from repro.kernels.megopolis.megopolis import megopolis_pallas_step, tiles_per_step
     from repro.kernels.megopolis.ref import megopolis_ref
 
     n = num_tiles * TILE
-    _, p, planes, state_shape = blocked_inputs(num_tiles, plane_dtype, state_dim,
-                                               jax.random.PRNGKey(num_tiles))
-    lw = quantise_plane(jax.random.normal(jax.random.PRNGKey(50 + num_tiles), (n,))
-                        * 2.0, plane_dtype)
-    lw2 = compress_plane(lw.reshape(n // 128, 128), plane_dtype)
-    g = tiles_per_step(num_tiles, planes.shape[0], lw2.dtype.itemsize)
-    offsets = blocked_offsets(table, n, g)
-    seed = jnp.array([77 + num_tiles], jnp.uint32)
+    tables = blocked_tables(table)
+    rows = [blocked_inputs(num_tiles, plane_dtype, state_dim,
+                           jax.random.PRNGKey(num_tiles + 100 * s))[1:]
+            for s in range(len(tables))]
+    lw = jnp.stack([quantise_plane(
+        jax.random.normal(jax.random.PRNGKey(50 + num_tiles + 100 * s), (n,)) * scale,
+        plane_dtype) for s, scale in zip(range(len(tables)), (2.0, 0.1))])
+    lw3 = compress_plane(lw.reshape(len(tables), n // 128, 128), plane_dtype)
+    planes = jnp.stack([planes for _, planes, _ in rows])
+    g = tiles_per_step(num_tiles, planes.shape[1], lw3.dtype.itemsize)
+    offsets = jnp.stack([blocked_offsets(t, n, g) for t in tables])
+    seeds = jnp.arange(len(tables), dtype=jnp.uint32) + 77 + num_tiles
 
     @jax.jit  # as every consumer runs the composition: XLA folds the
-    def composed(lw):  # division by the constant N as it folds the kernel's
+    def composed(lw, offsets, seed):  # division by the constant N as in the kernel
         w = quantise_plane(normalise_log_weights(lw), plane_dtype)
         return (effective_sample_size(lw) / jnp.float32(n), log_mean_weight(lw),
                 max_normalised_weight(lw),
                 megopolis_ref(w, offsets, seed, num_iters=BLOCKED_ITERS))
 
-    ess_n, incr, maxw, selected = composed(lw)
-    for thr, fires in ((0.7, True), (0.0, False)):
-        k2, out, stats = megopolis_pallas_step(
-            lw2, planes, offsets, seed, jnp.array([thr], jnp.float32),
+    for thr in (0.7, 0.0) + ((1.0,) if len(rows) > 1 else ()):
+        k3, out, stats = megopolis_pallas_step(
+            lw3, planes, offsets, seeds, jnp.array([thr], jnp.float32),
             num_iters=BLOCKED_ITERS, interpret=True)
-        assert bool(ess_n < thr) == fires
-        ancestors = selected if fires else jnp.arange(n, dtype=jnp.int32)
-        _assert_equal(k2.reshape(n), ancestors)
-        _assert_equal(unpack_state_planes(out.astype(p.dtype), state_shape),
-                      jnp.take(p, ancestors, axis=0))
-        _assert_equal(stats, jnp.stack([
-            ess_n, incr if fires else jnp.float32(0.0), jnp.float32(fires), maxw,
-        ]))
+        for s, (p, _, state_shape) in enumerate(rows):
+            ess_n, incr, maxw, selected = composed(lw[s], offsets[s], seeds[s:s + 1])
+            fires = thr >= (0.7 if s == 0 else 1.0)
+            assert bool(ess_n < thr) == fires
+            ancestors = selected if fires else jnp.arange(n, dtype=jnp.int32)
+            _assert_equal(k3[s].reshape(n), ancestors)
+            _assert_equal(unpack_state_planes(out[s].astype(p.dtype), state_shape),
+                          jnp.take(p, ancestors, axis=0))
+            _assert_equal(stats[s], jnp.stack([
+                ess_n, incr if fires else jnp.float32(0.0), jnp.float32(fires), maxw,
+            ]))
+            if len(rows) > 1:
+                one = megopolis_pallas_step(
+                    lw3[s:s + 1], planes[s:s + 1], offsets[s:s + 1], seeds[s:s + 1],
+                    jnp.array([thr], jnp.float32), num_iters=BLOCKED_ITERS,
+                    interpret=True)
+                for got, want in zip((k3, out, stats), one):
+                    _assert_equal(got[s], want[0])

@@ -102,13 +102,21 @@ def test_megopolis_apply_compiles_at_2_22(one_chip):
 
 
 @pytest.mark.parametrize("state_dim", [1, 4])
-@pytest.mark.parametrize("entry", ["apply_rows", "step_rows"])
+@pytest.mark.parametrize("entry", ["apply_rows", "step_rows", "batch", "apply_batch"])
 def test_megopolis_rows_compile_at_4x2_18(entry, state_dim, one_chip):
+    """The bank entries on one grid of S rows: per-row keys (``_rows``) or
+    one key and a shared table (``batch``, which takes no state, and
+    ``apply_batch``)."""
     r = _megopolis()
     s, n = 4, 1 << 18
-    shapes = _shapes(one_chip, ((s, 2), jnp.uint32), ((s, n), jnp.float32),
+    keys = KEY if entry.endswith("batch") else ((s, 2), jnp.uint32)
+    shapes = _shapes(one_chip, keys, ((s, n), jnp.float32),
                      (_state((s, n), state_dim), jnp.float32))
-    if entry == "apply_rows":
+    if entry == "batch":
+        compiled = _compile(r.batch, *shapes[:2])
+    elif entry == "apply_batch":
+        compiled = _compile(r.apply_batch, *shapes)
+    elif entry == "apply_rows":
         compiled = _compile(r.apply_rows, *shapes)
     else:
         compiled = _compile(lambda k, lw, p: r.step_rows(k, lw, p, THR), *shapes)
